@@ -49,19 +49,13 @@ def bench_provenance() -> Dict[str, object]:
       "numpy_version": np.__version__,
       "cpu_count": int(os.cpu_count() or 1),
   }
-  try:
-    import jax
+  import jax
 
-    from repro.explore.fleet import device_topology
-    prov["jax_version"] = jax.__version__
-    topo = device_topology()
-    prov["jax_device_kind"] = (topo["device_kinds"] or ["none"])[0]
-    prov["device_topology"] = topo
-  except Exception:  # noqa: BLE001 - jax is optional for numpy-only runs
-    prov["jax_version"] = "unavailable"
-    prov["jax_device_kind"] = "none"
-    prov["device_topology"] = {"platform": "none", "n_devices": 0,
-                               "device_kinds": []}
+  from repro.explore.fleet import device_topology
+  prov["jax_version"] = jax.__version__
+  topo = device_topology()
+  prov["jax_device_kind"] = topo["device_kinds"][0]
+  prov["device_topology"] = topo
   return prov
 
 

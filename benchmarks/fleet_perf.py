@@ -42,9 +42,24 @@ def _run_worker(n_devices: int, mode: str, n_per_type: int,
   return json.loads(proc.stdout.splitlines()[-1])
 
 
+def refuse_on_accelerator() -> None:
+  """The fleet points run in child processes, each with its own forced
+  XLA host-device count.  On an accelerator host that measures nothing
+  real, and a child cannot reach a chip that a parent process already
+  holds — so the fleet benchmark refuses there instead of hanging."""
+  from repro.explore.device import compiles_for_cpu
+  if not compiles_for_cpu():
+    raise RuntimeError(
+        "benchmarks.fleet_perf forces XLA host-device counts in child "
+        "processes and runs on CPU hosts only; on a TPU host run "
+        "`python chip_smoke.py --chips 4`, which drives the device fleet "
+        "in one process")
+
+
 def fleet_perf() -> None:
   from benchmarks.common import emit, write_bench_json
 
+  refuse_on_accelerator()
   smoke = os.environ.get("FLEET_BENCH_SCALE") == "smoke"
   if smoke:
     n_per_type, chunk_size = 200, 100        # 800 rows, 8 chunks

@@ -28,6 +28,8 @@ import time
 def main() -> None:
   n_devices, mode = int(sys.argv[1]), sys.argv[2]
   n_per_type, chunk_size = int(sys.argv[3]), int(sys.argv[4])
+  from benchmarks.fleet_perf import refuse_on_accelerator
+  refuse_on_accelerator()
   os.environ["XLA_FLAGS"] = (
       os.environ.get("XLA_FLAGS", "")
       + f" --xla_force_host_platform_device_count={n_devices}").strip()

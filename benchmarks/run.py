@@ -53,6 +53,8 @@ def main() -> None:
   if args.suite in _DEVICE_SUITES:
     from repro.explore.device import ensure_exact_cpu_codegen
     ensure_exact_cpu_codegen()
+  from repro.compile_cache import enable_compile_cache
+  enable_compile_cache()
   if args.json_dir:
     from benchmarks import common
     common.JSON_DIR = args.json_dir

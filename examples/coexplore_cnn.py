@@ -9,6 +9,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.supernet import Supernet, SupernetConfig, space_size
 from repro.explore import ExplorationSession, PolynomialBackend
 
@@ -19,6 +20,7 @@ def main():
   ap.add_argument("--archs", type=int, default=24)
   ap.add_argument("--hw-per-type", type=int, default=12)
   args = ap.parse_args()
+  enable_compile_cache()
 
   print(f"search space: {space_size():,} architectures (Table 4)")
   sn = Supernet(SupernetConfig(steps=args.steps, batch=32, image_size=16))

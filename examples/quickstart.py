@@ -10,12 +10,14 @@ Env: QUICKSTART_JIT=1        enable the approximate jax.jit device path
 import os
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.workloads import get_network
 from repro.explore import (DesignSpace, ExplorationSession,
                            PolynomialBackend, VectorOracleBackend)
 
 
 def main():
+  enable_compile_cache()
   layers = get_network("resnet20")
   space = DesignSpace()
   print(f"design space: {space!r}")

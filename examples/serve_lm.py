@@ -10,6 +10,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduce_for_smoke
 from repro.models.model import build_model
 from repro.serve.engine import EngineConfig, ServeEngine
@@ -22,6 +23,7 @@ def main():
   ap.add_argument("--new-tokens", type=int, default=16)
   ap.add_argument("--kv-quant", default="int8", choices=["none", "int8"])
   args = ap.parse_args()
+  enable_compile_cache()
 
   cfg = reduce_for_smoke(get_config(args.arch), d_model=128, n_layers=4,
                          vocab_size=2048)

@@ -10,6 +10,7 @@ import argparse
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduce_for_smoke
 from repro.data.synthetic import (DataCursor, MarkovTokenStream,
                                   TokenStreamConfig, token_batches)
@@ -32,6 +33,7 @@ def main():
   ap.add_argument("--full-config", action="store_true",
                   help="use the full architecture (needs accelerators)")
   args = ap.parse_args()
+  enable_compile_cache()
 
   cfg = get_config(args.arch)
   if not args.full_config:

@@ -374,9 +374,17 @@ def batch_inputs(table, scratch: Optional[Dict] = None
 
 
 def _decoder_levels_arr(words, xp):
-  # ceil absorbs log2's 1-ulp XLA/libm divergence everywhere except at
-  # exact powers of two, where IEEE log2 is exact in both — bit-safe
-  return xp.maximum(xp.ceil(xp.log2(xp.maximum(words, 2.0))), 1.0)  # repro: ignore[EXA002]
+  if xp is np:
+    return np.maximum(np.ceil(np.log2(np.maximum(words, 2.0))), 1.0)
+  # On device, ceil(log2(w)) is the bit length of ceil(w) - 1: integer
+  # arithmetic, exact everywhere.  A device log2 is not: the TPU's
+  # emulated float64 log2(2**19) lands above 19 (and fused XLA:CPU log2
+  # above 29 at 2**29), and ceil turns that ulp into a whole level.
+  import jax
+  int_t = xp.int64 if words.dtype == xp.float64 else xp.int32
+  w = xp.ceil(xp.maximum(words, 2.0)).astype(int_t) - 1
+  bits = xp.iinfo(int_t).bits - jax.lax.clz(w)
+  return xp.maximum(bits.astype(words.dtype), 1.0)
 
 
 def _sram_access_scale_arr(words, xp):

@@ -335,8 +335,8 @@ class VectorOracleBackend:
   def _x64(self):
     """Precision context: trace/run with float64 for the exact path."""
     if self.precision == "x64":
-      from jax.experimental import enable_x64
-      return enable_x64()
+      import jax
+      return jax.enable_x64(True)
     import contextlib
     return contextlib.nullcontext()
 
@@ -371,7 +371,6 @@ class VectorOracleBackend:
     devices = visible_devices()
     if len(devices) <= 1:
       return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.asarray(devices), ("batch",))
     out_specs = (P(None, "batch"), P("batch"), P("batch")) if joint \
@@ -384,8 +383,8 @@ class VectorOracleBackend:
       n = next(iter(inputs.values())).shape[0]
       pad = (-n) % len(devices)
       in_specs = (P("batch"),) + tuple(P() for _ in rest)
-      sharded = shard_map(rowwise, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+      sharded = jax.shard_map(rowwise, mesh=mesh, in_specs=in_specs,
+                              out_specs=out_specs)
       if pad:
         inputs = {k: jnp.concatenate([jnp.asarray(v),
                                       jnp.asarray(v[-1:]).repeat(pad, 0)])

@@ -39,6 +39,27 @@ from typing import Callable, Dict, Optional, Tuple
 import numpy as np
 
 
+def compiles_for_cpu() -> bool:
+  """Will this process's jax programs compile for XLA:CPU?
+
+  Decided without initializing a backend (that would latch XLA_FLAGS):
+  an initialized backend answers directly; otherwise ``JAX_PLATFORMS``
+  names the platform; otherwise jax picks an attached accelerator when
+  the PCI bus shows one (the same probe jax runs before loading libtpu).
+  """
+  import sys
+  xb = sys.modules.get("jax._src.xla_bridge")
+  if xb is not None and getattr(xb, "_backends", None):
+    import jax
+    return jax.default_backend() == "cpu"
+  platforms = os.environ.get("JAX_PLATFORMS", "").strip()
+  if platforms:
+    return platforms.split(",")[0].strip().lower() == "cpu"
+  from jax._src import hardware_utils
+  n_tpu, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+  return n_tpu == 0 and not hardware_utils.has_visible_nvidia_gpu()
+
+
 def ensure_exact_cpu_codegen() -> None:
   """Make XLA:CPU arithmetic bit-compatible with numpy.
 
@@ -54,12 +75,24 @@ def ensure_exact_cpu_codegen() -> None:
       ``x * (1/const)`` and reassociates constant multiplies.
 
   XLA latches its flags at the process's first compilation, so this runs
-  at this module's import and from ``VectorOracleBackend(jit=True)``
-  construction — both precede our program builds.  If your process
-  compiles other jax code first, set ``XLA_FLAGS="--xla_cpu_max_isa=AVX
+  from ``VectorOracleBackend(jit=True)`` construction, which precedes
+  our program builds.  If your process compiles other jax code first,
+  set ``XLA_FLAGS="--xla_cpu_max_isa=AVX
   --xla_disable_hlo_passes=algsimp"`` in the environment yourself
   (``tests/conftest.py`` and ``benchmarks/run.py`` do exactly that).
+
+  In a process that compiles for an accelerator (see
+  :func:`compiles_for_cpu`) this does nothing.  XLA_FLAGS reach every
+  backend's compiler, and the TPU compiler honours
+  ``--xla_disable_hlo_passes=algsimp``, yet cannot become numpy-exact
+  with it: the TPU has no float64 units and emulates float64, and on a
+  v5e every float64 arithmetic op differs from numpy in the last bits
+  with or without the flag.  With it, the joint program even fails to
+  run ("HLO transpose is not implemented on TPU").  ``chip_smoke.py``
+  measures what the chip gives, op by op.
   """
+  if not compiles_for_cpu():
+    return
   flags = os.environ.get("XLA_FLAGS", "")
   if "xla_cpu_max_isa" not in flags:
     flags = (flags + " --xla_cpu_max_isa=AVX").strip()
@@ -135,10 +168,9 @@ def exact_codegen_active() -> bool:
   global _EXACT_PROBE
   if _EXACT_PROBE is None:
     import jax
-    from jax.experimental import enable_x64
     x = np.linspace(0.5, 1e6, 4096)
     y = x[::-1].copy()
-    with enable_x64():
+    with jax.enable_x64(True):
       got = jax.jit(lambda a, b: (0.028 * a + 0.006 * b,
                                   a / 3.0, a * 0.3 * 0.7))(x, y)
       got = tuple(np.asarray(v) for v in got)
@@ -157,6 +189,13 @@ def warn_if_inexact_codegen() -> None:
     return
   _EXACT_WARNED = True
   import warnings
+  if not compiles_for_cpu():
+    warnings.warn(
+        "VectorOracleBackend(jit=True, precision='x64') is not "
+        "bit-identical to numpy on this accelerator: it emulates float64, "
+        "so results differ from numpy's in the last bits (chip_smoke.py "
+        "measures the difference op by op).", RuntimeWarning, stacklevel=3)
+    return
   warnings.warn(
       "VectorOracleBackend(jit=True, precision='x64') cannot be "
       "bit-identical to numpy in this process: XLA compiled with FMA "

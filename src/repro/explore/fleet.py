@@ -83,14 +83,11 @@ def visible_devices() -> Tuple[object, ...]:
 
 def device_topology() -> Dict[str, object]:
   """Provenance-stamp description of the fleet (platform, count, kinds).
-  Import-safe: degrades to an empty topology when jax is unavailable."""
-  try:
-    devs = visible_devices()
-  except Exception:
-    return {"platform": "none", "n_devices": 0, "device_kinds": []}
-  kinds = sorted({str(getattr(d, "device_kind", "unknown")) for d in devs})
-  platform = str(getattr(devs[0], "platform", "unknown")) if devs else "none"
-  return {"platform": platform, "n_devices": len(devs),
+  An enumeration error propagates: a stamp never names a device that
+  was not found."""
+  devs = visible_devices()
+  kinds = sorted({str(d.device_kind) for d in devs})
+  return {"platform": str(devs[0].platform), "n_devices": len(devs),
           "device_kinds": kinds}
 
 

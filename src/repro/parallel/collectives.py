@@ -89,7 +89,6 @@ def dp_compressed_grads(loss_fn, params, batch, mesh: Mesh,
                         axis_name: str = "data"):
   """Pure-DP demonstration path: per-shard grads + int8 all-reduce via
   shard_map (params replicated, batch sharded on `axis_name`)."""
-  from jax.experimental.shard_map import shard_map
 
   def shard_fn(params, batch):
     loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -101,5 +100,5 @@ def dp_compressed_grads(loss_fn, params, batch, mesh: Mesh,
 
   pspec = jax.tree_util.tree_map(lambda _: P(), params)
   bspec = jax.tree_util.tree_map(lambda _: P(axis_name), batch)
-  return shard_map(shard_fn, mesh=mesh, in_specs=(pspec, bspec),
-                   out_specs=(P(), pspec), check_rep=False)(params, batch)
+  return jax.shard_map(shard_fn, mesh=mesh, in_specs=(pspec, bspec),
+                       out_specs=(P(), pspec), check_vma=False)(params, batch)

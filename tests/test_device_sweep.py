@@ -412,3 +412,73 @@ class TestJitCacheBound:
     for i in range(backend.JIT_CACHE_SIZE + 3):
       backend.evaluate_table(tbl, nets[i:i + 2], f"net{i}")
     assert len(backend._jit_cache) <= backend.JIT_CACHE_SIZE
+
+
+def _fresh_python(code: str, **env) -> str:
+  """Run ``code`` in a fresh interpreter (XLA flags and JAX's cache
+  placement latch per process) and return its last stdout line."""
+  import os
+  import subprocess
+  import sys
+  root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+  full = {k: v for k, v in os.environ.items()
+          if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
+  full["PYTHONPATH"] = os.path.join(root, "src")
+  full.update(env)
+  proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                        text=True, env=full, timeout=120, cwd=root)
+  assert proc.returncode == 0, proc.stderr[-2000:]
+  return proc.stdout.strip().splitlines()[-1]
+
+
+class TestChipPathSetup:
+  """What a process sets up before its first compile: CPU exactness
+  flags only where it compiles for the CPU, and one fixed compile-cache
+  directory."""
+
+  @pytest.mark.parametrize("platforms,want_flags", [
+      ("cpu", True), ("tpu", False), ("tpu,cpu", False)])
+  def test_exactness_flags_only_for_cpu_compiles(self, platforms,
+                                                 want_flags):
+    out = _fresh_python(
+        "import os\n"
+        "from repro.explore.device import (compiles_for_cpu,\n"
+        "                                  ensure_exact_cpu_codegen)\n"
+        "ensure_exact_cpu_codegen()\n"
+        "print(compiles_for_cpu(), os.environ.get('XLA_FLAGS', ''))",
+        JAX_PLATFORMS=platforms)
+    on_cpu, flags = (out.split(" ", 1) + [""])[:2]
+    assert on_cpu == str(want_flags)
+    assert ("algsimp" in flags) == want_flags
+    assert ("xla_cpu_max_isa=AVX" in flags) == want_flags
+
+  def test_compile_cache_defaults_to_the_checkout(self):
+    from repro.compile_cache import CHECKOUT_CACHE_DIR
+    out = _fresh_python(
+        "import jax\n"
+        "from repro.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache(), jax.config.jax_compilation_cache_dir)",
+        JAX_PLATFORMS="cpu")
+    assert out.split() == [CHECKOUT_CACHE_DIR, CHECKOUT_CACHE_DIR]
+    assert CHECKOUT_CACHE_DIR.endswith(".jax_cache")
+
+  def test_compile_cache_follows_the_environment(self, tmp_path):
+    out = _fresh_python(
+        "import jax\n"
+        "from repro.compile_cache import enable_compile_cache\n"
+        "print(enable_compile_cache(), jax.config.jax_compilation_cache_dir)",
+        JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    assert out.split() == [str(tmp_path), str(tmp_path)]
+
+  def test_fleet_benchmark_refuses_accelerator_hosts(self):
+    out = _fresh_python(
+        "import sys\n"
+        "sys.path.insert(0, '.')\n"
+        "from benchmarks.fleet_perf import refuse_on_accelerator\n"
+        "try:\n"
+        "  refuse_on_accelerator()\n"
+        "  print('ran')\n"
+        "except RuntimeError as e:\n"
+        "  print('refused', 'chip_smoke.py --chips 4' in str(e))",
+        JAX_PLATFORMS="tpu")
+    assert out == "refused True"

@@ -98,14 +98,13 @@ class TestCompressedCollectives:
   @pytest.mark.skipif(len(jax.devices()) < 2, reason="needs >=2 devices")
   def test_compressed_psum_matches_psum(self):
     mesh = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
-    from jax.experimental.shard_map import shard_map
     x = jax.random.normal(KEY, (2, 256))
 
     def f(x):
       return collectives.compressed_psum_int8(x[0], "data")
 
-    got = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
-                    check_rep=False)(x)
+    got = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
+                        check_vma=False)(x)
     want = jnp.sum(x, axis=0)
     assert float(jnp.max(jnp.abs(got - want))) < float(
         jnp.max(jnp.abs(x))) / 40.0

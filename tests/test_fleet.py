@@ -120,6 +120,25 @@ class TestPin:
     assert seen == [None]
 
 
+class TestDeviceTopology:
+
+  def test_names_the_devices_found(self):
+    devs = visible_devices()
+    assert device_topology() == {
+        "platform": devs[0].platform, "n_devices": len(devs),
+        "device_kinds": sorted({d.device_kind for d in devs})}
+
+  def test_enumeration_error_propagates(self, monkeypatch):
+    from repro.explore import fleet
+
+    def no_backend():
+      raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(fleet, "visible_devices", no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+      fleet.device_topology()
+
+
 # ---------------------------------------------------------------------------
 # the health registry
 # ---------------------------------------------------------------------------
