@@ -268,8 +268,13 @@ def characterize(cfg: AcceleratorConfig,
   from repro.core.dataflow import layer_energy_pj  # local to avoid cycle
   per_e = [layer_energy_pj(cfg, l, s, clk, leak) * 1e-9
            for l, s in zip(layers, stats)]
-  util = (sum(s.utilization * s.cycles for s in stats)
-          / max(sum(per_cyc), 1e-12))
+  # left to right, as the vectorized oracle adds: builtin sum() of floats
+  # compensates its rounding (Python >= 3.12) and lands an ulp away
+  util_weighted = total_cycles = 0.0
+  for s in stats:
+    util_weighted += s.utilization * s.cycles
+    total_cycles += s.cycles
+  util = util_weighted / max(total_cycles, 1e-12)
   return Characterization(
       clock_mhz=clk, area_mm2=area_mm2(cfg), power_mw=power_mw(cfg),
       latency_s=latency_s, energy_mj=energy_mj,

@@ -51,7 +51,7 @@ import dataclasses
 import os
 import time
 from collections import deque
-from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import (Callable, Dict, Iterable, Iterator, Optional, Sequence,
                     Tuple)
 
@@ -522,9 +522,10 @@ def run_stream(tasks: Iterable[Task], reducers: Dict[str, Reducer],
 
   ``workers > 1`` evaluates chunks on a thread pool with a bounded
   in-flight window (2x workers), so peak memory stays O(window x chunk);
-  folds happen on the submitting thread only.  Completion order is
-  nondeterministic — reducers are chunk-order invariant, so results are
-  not.
+  folds happen on the submitting thread only, in submission order
+  whatever order the workers finish in, so a sweep folds the same way on
+  any core count (Pareto/top-k do not care; StatsAccumulator's merge
+  does, in the last bits).
 
   Failure semantics (see :mod:`repro.explore.resilience` and
   docs/explore.md "Failure semantics & resume"):
@@ -641,32 +642,31 @@ def run_stream(tasks: Iterable[Task], reducers: Dict[str, Reducer],
       finish(*window.popleft())
   else:
     with ThreadPoolExecutor(max_workers=workers) as pool:
-      pending: Dict = {}  # future -> global chunk index
+      pending: "deque" = deque()  # (future, global chunk index), FIFO
 
-      def drain(ready) -> None:
-        for fut in ready:
-          index = pending.pop(fut)
-          try:
-            res = fut.result()
-          except Exception as e:
-            fail(index, e)
-          finish(index, res)
+      def drain_oldest() -> None:
+        fut, index = pending[0]
+        wait((fut,), timeout=POOL_WAIT_SECONDS)
+        if not fut.done():
+          return
+        pending.popleft()
+        try:
+          res = fut.result()
+        except Exception as e:
+          fail(index, e)
+        finish(index, res)
 
       try:
         for index, task in indexed(tasks):
-          pending[pool.submit(execute, task)] = index
+          pending.append((pool.submit(execute, task), index))
           while len(pending) >= 2 * workers:
-            ready, _ = wait(set(pending), timeout=POOL_WAIT_SECONDS,
-                            return_when=FIRST_COMPLETED)
-            drain(ready)
+            drain_oldest()
         while pending:
-          ready, _ = wait(set(pending), timeout=POOL_WAIT_SECONDS,
-                          return_when=FIRST_COMPLETED)
-          drain(ready)
+          drain_oldest()
       except Exception:
         # fatal: drop queued chunks so the pool shuts down promptly
         # instead of grinding through the whole in-flight window
-        for fut in pending:
+        for fut, _ in pending:
           fut.cancel()
         raise
   checkpoint(force=True)

@@ -334,7 +334,7 @@ def brute_force_front(obj: np.ndarray) -> np.ndarray:
 class TestBlockDecomposedParetoND:
   @given(st.integers(0, 10_000), st.integers(1, 120), st.integers(3, 4))
   @settings(max_examples=20, deadline=None)
-  def test_blocked_matches_brute_force(self, seed, n, d, monkeypatch=None):
+  def test_blocked_matches_brute_force(self, seed, n, d):
     rng = np.random.RandomState(seed)
     obj = rng.randint(0, 6, size=(n, d)).astype(np.float64)  # many dups
     assert np.array_equal(pareto_mask(obj), brute_force_front(obj))
