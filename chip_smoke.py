@@ -26,7 +26,6 @@ import argparse
 import json
 import os
 import sys
-import threading
 import time
 import traceback
 
@@ -62,29 +61,6 @@ SHARD_ARCHS = 40                    # four chips: 40 archs x 2,500 HW rows
 
 def log(msg: str) -> None:
   print(msg, flush=True)
-
-
-class CompileClock:
-  """Sums XLA backend compile time (persistent-cache hits skip it)."""
-
-  def __init__(self):
-    from jax import monitoring
-    from jax._src import dispatch
-    self.seconds = 0.0
-    self.count = 0
-    lock = threading.Lock()  # compiles may finish on several threads
-    event = dispatch.BACKEND_COMPILE_EVENT
-
-    def listener(name, secs, **_):
-      if name == event:
-        with lock:
-          self.seconds += secs
-          self.count += 1
-
-    monitoring.register_event_duration_secs_listener(listener)
-
-  def mark(self):
-    return self.seconds, self.count
 
 
 def exactness_probe(jax) -> dict:
@@ -184,12 +160,15 @@ def report(phase: str, wall: float, compile_s: float, n_compiles: int,
   return ok
 
 
-def timed(clock, fn):
-  s0, n0 = clock.mark()
+def timed(fn):
+  """``fn()``, its wall seconds, and the seconds and count of the XLA
+  compiles the process made meanwhile (persistent-cache hits make none)."""
+  from repro.explore.spans import compile_totals
+  s0, n0 = compile_totals()
   t0 = time.perf_counter()
   out = fn()
   wall = time.perf_counter() - t0
-  s1, n1 = clock.mark()
+  s1, n1 = compile_totals()
   return out, wall, s1 - s0, n1 - n0
 
 
@@ -217,7 +196,7 @@ def on_device(device, task):
     return task().resolve()
 
 
-def compile_ahead(clock, jobs) -> None:
+def compile_ahead(jobs) -> None:
   """Dispatch one chunk of every program shape (and device) the phases
   will run, all at once.  The sweeps would compile them one after
   another; here XLA compiles them concurrently, and the sweeps find them
@@ -229,7 +208,7 @@ def compile_ahead(clock, jobs) -> None:
       for fut in [ex.submit(job) for job in jobs]:
         fut.result()
 
-  _, wall, cs, nc = timed(clock, all_jobs)
+  _, wall, cs, nc = timed(all_jobs)
   log(f"compile_ahead programs={len(jobs)} wall_s={wall} "
       f"compile_s_summed={cs} n_compiles={nc}")
 
@@ -262,11 +241,11 @@ class PlainPhase:
         stream=True, reducers=red, chunk_size=PLAIN_CHUNK)
     return res, red
 
-  def run(self, clock, exact: bool) -> bool:
+  def run(self, exact: bool) -> bool:
     from repro.explore import VectorOracleBackend
-    (res, red), wall, cs, nc = timed(clock, lambda: self.sweep(self.backend))
+    (res, red), wall, cs, nc = timed(lambda: self.sweep(self.backend))
     (_, ref), ref_wall, _, _ = timed(
-        clock, lambda: self.sweep(VectorOracleBackend(chunk_size=PLAIN_CHUNK)))
+        lambda: self.sweep(VectorOracleBackend(chunk_size=PLAIN_CHUNK)))
     comps = {k: compare(red[k], ref[k], exact) for k in red}
     return report(self.name, wall, cs, nc, res, ref_wall, comps,
                   meta_ok(res.meta, res.n_rows, fused=True))
@@ -312,12 +291,12 @@ class JointPhase:
         chunk_size=JOINT_CHUNK, pool=pool)
     return res, red
 
-  def run(self, clock, exact: bool) -> bool:
+  def run(self, exact: bool) -> bool:
     from repro.explore import VectorOracleBackend
     (res, red), wall, cs, nc = timed(
-        clock, lambda: self.sweep(self.backend, self.pool))
+        lambda: self.sweep(self.backend, self.pool))
     (_, ref), ref_wall, _, _ = timed(
-        clock, lambda: self.sweep(VectorOracleBackend(chunk_size=JOINT_CHUNK)))
+        lambda: self.sweep(VectorOracleBackend(chunk_size=JOINT_CHUNK)))
     comps = {k: compare(red[k], ref[k], exact) for k in red}
     checks = meta_ok(res.meta, res.n_rows, fused=True)
     if self.pool is not None:
@@ -363,11 +342,11 @@ class SearchPhase:
         generations=SEARCH_GENERATIONS, seed=17, reducers=red)
     return res, red
 
-  def run(self, clock, exact: bool) -> bool:
+  def run(self, exact: bool) -> bool:
     from repro.explore import VectorOracleBackend
-    (res, red), wall, cs, nc = timed(clock, lambda: self.sweep(self.backend))
+    (res, red), wall, cs, nc = timed(lambda: self.sweep(self.backend))
     (ref_res, ref), ref_wall, _, _ = timed(
-        clock, lambda: self.sweep(VectorOracleBackend()))
+        lambda: self.sweep(VectorOracleBackend()))
     comps = {"pareto": compare(red["pareto"], ref["pareto"], exact)}
     checks = meta_ok(res.meta, res.n_rows, fused=False)
     checks["same_evaluations"] = res.n_rows == ref_res.n_rows
@@ -396,12 +375,12 @@ class ShardedPhase:
   def warm_jobs(self):
     return [lambda: self.backend.co_evaluate_table(self.hw, self.stack)]
 
-  def run(self, clock, exact: bool) -> bool:
+  def run(self, exact: bool) -> bool:
     from repro.core import oracle
     from repro.explore import VectorOracleBackend
     from repro.explore.fleet import visible_devices
     dev, wall, cs, nc = timed(
-        clock, lambda: self.backend.co_evaluate_table(self.hw, self.stack))
+        lambda: self.backend.co_evaluate_table(self.hw, self.stack))
     ref = VectorOracleBackend(chunk_size=JOINT_CHUNK).co_evaluate_table(
         self.hw, self.stack)
     identical = all(np.array_equal(getattr(dev, c), getattr(ref, c))
@@ -445,8 +424,8 @@ def run(chips: int) -> dict:
   from repro.explore.fleet import device_topology
   log(f"topology {device_topology()}")
 
-  clock = CompileClock()
-  (probe, wall, cs, _) = timed(clock, lambda: exactness_probe(jax))
+  from repro.explore.spans import compile_totals
+  (probe, wall, cs, _) = timed(lambda: exactness_probe(jax))
   for name, r in probe.items():
     log(f"exact op={name} identical={r['identical']} n_diff={r['n_diff']} "
         f"max_rel={r['max_rel']}")
@@ -461,9 +440,10 @@ def run(chips: int) -> dict:
   else:
     from repro.explore import DevicePool
     phases = [JointPhase(pool=DevicePool()), ShardedPhase()]
-  compile_ahead(clock, [job for p in phases for job in p.warm_jobs()])
-  oks = [p.run(clock, exact) for p in phases]
-  log(f"compile_s_total={clock.seconds} n_compiles={clock.count}")
+  compile_ahead([job for p in phases for job in p.warm_jobs()])
+  oks = [p.run(exact) for p in phases]
+  compile_s, n_compiles = compile_totals()
+  log(f"compile_s_total={compile_s} n_compiles={n_compiles}")
   return {"ok": all(oks), "device": device}
 
 

@@ -38,6 +38,7 @@ from repro.core import ppa as ppa_lib
 from repro.core.dataflow import AcceleratorConfig, ConvLayer, LayerStack
 from repro.core.pe import PAPER_PE_TYPES
 from repro.core.table import ConfigTable
+from repro.explore import spans
 from repro.explore.frame import ResultFrame
 
 Configs = Union[Sequence[AcceleratorConfig], ConfigTable]
@@ -394,7 +395,8 @@ class VectorOracleBackend:
         return l[:, :n], p[:n], a[:n]
       return l[:n], p[:n], a[:n]
 
-    return padded
+    from repro.explore.device import named
+    return named(padded, fn.__name__ + "_sharded")
 
   @staticmethod
   def _pinned():
@@ -404,15 +406,20 @@ class VectorOracleBackend:
     return fleet.pinned_device()
 
   @staticmethod
-  def _place(inputs, dev):
-    """Commit a chunk's input arrays to the pinned device so the jitted
-    program executes there.  Must run inside the ``_x64`` context —
-    ``device_put`` canonicalizes dtypes, and float64 inputs would be
-    silently downcast outside it."""
-    if dev is None:
+  def _place(inputs, dev, commit: bool = False):
+    """Copy a chunk's input columns to the pinned device, so the jitted
+    program executes there; with ``commit`` and no pin, to the default
+    device, so that the copy is a stage of its own rather than part of
+    the call.  Unpinned, uncommitted inputs stay on the host (the
+    row-sharded program splits them itself).  Must run inside the
+    ``_x64`` context — ``device_put`` canonicalizes dtypes, and float64
+    inputs would be silently downcast outside it."""
+    if dev is None and not commit:
       return inputs
     import jax
-    return jax.device_put(inputs, dev)
+    with spans.span("place"):
+      spans.count_bytes(sum(v.nbytes for v in inputs.values()))
+      return jax.device_put(inputs, dev)
 
   def _eval_fn(self, layers: Tuple[ConvLayer, ...], plan=None,
                pinned: bool = False):
@@ -473,11 +480,14 @@ class VectorOracleBackend:
     import jax
     from repro.explore import device as device_lib
     layers = tuple(layers)
-    inputs = oracle.batch_inputs(table)
-    dev = self._pinned()
-    with self._x64():
-      inputs = self._place(inputs, dev)
-      out = self._eval_fn(layers, pinned=dev is not None)(inputs)
+    with spans.span("dispatch"):
+      with spans.span("batch_inputs"):
+        inputs = oracle.batch_inputs(table)
+      dev = self._pinned()
+      with self._x64():
+        inputs = self._place(inputs, dev)
+        with spans.span("launch"):
+          out = self._eval_fn(layers, pinned=dev is not None)(inputs)
 
     def finalize():
       l, p, a = (np.asarray(jax.device_get(o), np.float64) for o in out)
@@ -493,13 +503,17 @@ class VectorOracleBackend:
     resolve, matching the host streaming task)."""
     import jax
     from repro.explore import device as device_lib
-    inputs = oracle.batch_inputs(hw)
-    unique_cols, slot_ids = stack.dedup_slots() if dedup is None else dedup
-    dev = self._pinned()
-    with self._x64():
-      inputs = self._place(inputs, dev)
-      out = self._joint_fn(pinned=dev is not None)(
-          inputs, unique_cols, slot_ids, stack.valid, np.zeros(0))
+    with spans.span("dispatch"):
+      with spans.span("batch_inputs"):
+        inputs = oracle.batch_inputs(hw)
+      unique_cols, slot_ids = stack.dedup_slots() if dedup is None \
+          else dedup
+      dev = self._pinned()
+      with self._x64():
+        inputs = self._place(inputs, dev)
+        with spans.span("launch"):
+          out = self._joint_fn(pinned=dev is not None)(
+              inputs, unique_cols, slot_ids, stack.valid, np.zeros(0))
 
     def finalize():
       lat, pwr, area = (np.asarray(jax.device_get(o), np.float64)
@@ -517,11 +531,13 @@ class VectorOracleBackend:
     O(survivors) device->host transfer."""
     from repro.explore import device as device_lib
     layers = tuple(layers)
-    inputs = oracle.batch_inputs(table)
-    dev = self._pinned()
-    with self._x64():
-      inputs = self._place(inputs, dev)
-      outputs = self._eval_fn(layers, plan)(inputs)
+    with spans.span("dispatch"):
+      with spans.span("batch_inputs"):
+        inputs = oracle.batch_inputs(table)
+      with self._x64():
+        inputs = self._place(inputs, self._pinned(), commit=True)
+        with spans.span("launch"):
+          outputs = self._eval_fn(layers, plan)(inputs)
     return device_lib.PendingFused(outputs, plan, table, idx, network)
 
   def fused_co_eval_pending(self, hw: ConfigTable, stack: LayerStack,
@@ -530,14 +546,17 @@ class VectorOracleBackend:
                             arch_lookup: Tuple[object, ...], dedup=None):
     """Joint twin of :meth:`fused_eval_pending`."""
     from repro.explore import device as device_lib
-    inputs = oracle.batch_inputs(hw)
-    unique_cols, slot_ids = stack.dedup_slots() if dedup is None else dedup
     accs = np.asarray(accs, np.float64)
-    dev = self._pinned()
-    with self._x64():
-      inputs = self._place(inputs, dev)
-      outputs = self._joint_fn(plan)(inputs, unique_cols, slot_ids,
-                                     stack.valid, accs)
+    with spans.span("dispatch"):
+      with spans.span("batch_inputs"):
+        inputs = oracle.batch_inputs(hw)
+      unique_cols, slot_ids = stack.dedup_slots() if dedup is None \
+          else dedup
+      with self._x64():
+        inputs = self._place(inputs, self._pinned(), commit=True)
+        with spans.span("launch"):
+          outputs = self._joint_fn(plan)(inputs, unique_cols, slot_ids,
+                                         stack.valid, accs)
     return device_lib.PendingFused(outputs, plan, hw, idx, network,
                                    n_hw=len(hw), arch_lo=arch_lo, accs=accs,
                                    arch_lookup=arch_lookup)

@@ -209,6 +209,7 @@ def warn_if_inexact_codegen() -> None:
 from repro.core import oracle
 from repro.core.dataflow import ConvLayer
 from repro.core.table import ConfigTable
+from repro.explore import spans
 from repro.explore.frame import BASE_COLUMNS, DERIVED_COLUMNS, ResultFrame
 
 # columns the device programs can materialize (frame.column equivalents);
@@ -432,6 +433,13 @@ def _reduce_outputs(cols, plan: DevicePlan, grouped: bool, jnp, jax):
 # program builders (returned callables are pure: backend jits them)
 # ---------------------------------------------------------------------------
 
+def named(fn: Callable, name: str) -> Callable:
+  """``fn`` under ``name``: jax names the jitted program ``jit_<name>``,
+  so a device trace tells the programs apart."""
+  fn.__name__ = fn.__qualname__ = name
+  return fn
+
+
 def make_eval_fn(layers: Tuple[ConvLayer, ...],
                  plan: Optional[DevicePlan]) -> Callable:
   """Plain-sweep program: inputs bundle -> (lat, pwr, area)[, reductions].
@@ -452,7 +460,7 @@ def make_eval_fn(layers: Tuple[ConvLayer, ...],
                            ch.area_mm2[None, :], jnp)
     return full, _reduce_outputs(cols, plan, grouped=False, jnp=jnp, jax=jax)
 
-  return run
+  return named(run, "eval" if plan is None else "fused_eval")
 
 
 def make_joint_fn(plan: Optional[DevicePlan]) -> Callable:
@@ -480,7 +488,7 @@ def make_joint_fn(plan: Optional[DevicePlan]) -> Callable:
         jnp.broadcast_to(ch.area_mm2[None, :], lat.shape), jnp, accs=accs)
     return full, _reduce_outputs(cols, plan, grouped=True, jnp=jnp, jax=jax)
 
-  return run
+  return named(run, "joint" if plan is None else "fused_joint")
 
 
 def joint_chunk_frame(lat: np.ndarray, pwr: np.ndarray, area: np.ndarray,
@@ -611,34 +619,49 @@ class PendingFused(_PendingBase):
   # -- resolution -----------------------------------------------------------
 
   def resolve(self) -> FusedChunk:
-    payloads: Dict[str, tuple] = {}
-    full = None
-    transferred = 0
-    overflows = 0
-    for name, spec in self.plan:
-      out = self._reduced[name]
-      if isinstance(spec, ParetoSpec):
-        count = int(out["count"])
-        if count > self.plan.cap:  # rare: fetch the full chunk instead
-          overflows += 1
-          if full is None:
-            full = self.full_frame()
-            transferred += len(self.indices)
+    """Wait for the program, slice each pareto reducer's survivors on the
+    device, fetch what every reducer needs, and build its payload: one
+    span per phase, around the loop over reducers."""
+    with spans.span("resolve") as resolving:
+      with spans.span("wait"):  # the first blocking read
+        counts = {name: int(self._reduced[name]["count"])
+                  for name, spec in self.plan
+                  if isinstance(spec, ParetoSpec)}
+      resolving.note(survivors=sum(counts.values()))
+      overflows = sum(c > self.plan.cap for c in counts.values())
+      with spans.span("slice"):
+        sliced = {name: (self._reduced[name]["idx"][:c],
+                         [r[:c] for r in self._reduced[name]["rows"]])
+                  for name, c in counts.items() if c <= self.plan.cap}
+      with spans.span("fetch"):
+        # rare: a count over the cap folds the full chunk instead
+        full = self.full_frame() if overflows else None
+        host = {name: (np.asarray(idx, np.int64),
+                       [np.asarray(r, np.float64) for r in rows])
+                for name, (idx, rows) in sliced.items()}
+        for name, spec in self.plan:
+          out = self._reduced[name]
+          if isinstance(spec, TopKSpec):
+            host[name] = (np.asarray(out["idx"], np.int64),
+                          [np.asarray(r, np.float64) for r in out["rows"]])
+          elif isinstance(spec, StatsSpec):
+            host[name] = {k: float(out[k]) if k != "n" else int(out[k])
+                          for k in out}
+          elif isinstance(spec, HistSpec):
+            host[name] = np.asarray(out["counts"], np.int64)
+      payloads: Dict[str, tuple] = {}
+      transferred = len(self.indices) if overflows else 0
+      for name, spec in self.plan:
+        if isinstance(spec, StatsSpec):
+          payloads[name] = ("stats", host[name])
+        elif isinstance(spec, HistSpec):
+          payloads[name] = ("hist", host[name])
+        elif name not in host:  # an overflowing pareto reducer
           payloads[name] = ("rows",) + full
-          continue
-        local = np.asarray(out["idx"][:count], np.int64)
-        transferred += count
-        payloads[name] = ("rows", self._mini_frame(local, [
-            r[:count] for r in out["rows"]]), self.indices[local])
-      elif isinstance(spec, TopKSpec):
-        local = np.asarray(out["idx"], np.int64)
-        transferred += local.size
-        payloads[name] = ("rows", self._mini_frame(local, out["rows"]),
-                          self.indices[local])
-      elif isinstance(spec, StatsSpec):
-        payloads[name] = ("stats", {k: float(out[k]) if k != "n" else
-                                    int(out[k]) for k in out})
-      else:
-        payloads[name] = ("hist", np.asarray(out["counts"], np.int64))
-    return FusedChunk(payloads=payloads, n_rows=len(self.indices),
-                      n_transferred=transferred, n_overflows=overflows)
+        else:
+          local, rows = host[name]
+          transferred += local.size
+          payloads[name] = ("rows", self._mini_frame(local, rows),
+                            self.indices[local])
+      return FusedChunk(payloads=payloads, n_rows=len(self.indices),
+                        n_transferred=transferred, n_overflows=overflows)

@@ -62,6 +62,7 @@ from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 import numpy as np
 
 from repro.core.seeding import derive_seed
+from repro.explore import spans
 from repro.explore.resilience import (ChunkError, ChunkTask, CircuitBreaker,
                                       ResiliencePolicy, SweepJournal,
                                       SweepKilled)
@@ -422,8 +423,23 @@ def run_fleet(tasks: Iterable[ChunkTask], reducers: Dict[str, object],
   sharding, straggler speculation, elastic resharding, and the SDC
   sentinel layered on top.  Bit-identity: reducers are chunk-order
   invariant and every re-execution is a pure recomputation, so the final
-  fronts match a solo single-device run exactly.
+  fronts match a solo single-device run exactly.  ``meta`` carries the
+  sweep's span counters (:mod:`repro.explore.spans`) and
+  ``fleet_device_compiles``, the sweep's compiles on each pool device.
   """
+  with spans.recording() as recorder:
+    with spans.span("stream"):
+      out = _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead,
+                         resume_from, journal_key, checkpoint_every)
+    out.meta.update(recorder.meta())
+    out.meta["fleet_device_compiles"] = recorder.compiles_per_device(
+        pool.n_devices)
+  return out
+
+
+def _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead, resume_from,
+                 journal_key, checkpoint_every):
+  """The body of :func:`run_fleet`, inside its span."""
   # deferred: streaming imports fleet lazily too (pool= routing)
   from repro.explore.streaming import (DISPATCH_AHEAD, StreamResult,
                                        fold_chunk, new_counters)
@@ -493,7 +509,8 @@ def run_fleet(tasks: Iterable[ChunkTask], reducers: Dict[str, object],
 
   def finish_fold(index, result) -> None:
     try:
-      fold_chunk(reducers, counters, result)
+      with spans.at(index):
+        fold_chunk(reducers, counters, result)
     except Exception as e:
       fail(index, e)
     done_chunks.add(index)
@@ -554,7 +571,7 @@ def run_fleet(tasks: Iterable[ChunkTask], reducers: Dict[str, object],
     start = time.perf_counter()
     try:
       if dev is not None:
-        with pin(pool.device(dev)):
+        with pin(pool.device(dev)), spans.at(index, dev):
           out = execute(task)
       elif has_device_rung:
         # every device quarantined: the terminal numpy rung is the safe
@@ -596,7 +613,8 @@ def run_fleet(tasks: Iterable[ChunkTask], reducers: Dict[str, object],
         continue
       shard.speculated = True
       try:
-        with pin(pool.device(alt)):
+        with pin(pool.device(alt)), spans.at(shard.index, alt), \
+            spans.span("speculate"):
           out = execute(shard.task)
       except SweepKilled:
         checkpoint(force=True)
@@ -629,8 +647,10 @@ def run_fleet(tasks: Iterable[ChunkTask], reducers: Dict[str, object],
     pick = int(sdc_rng.randint(len(buf)))
     index, task, result = buf[pick]
     pool.note_corruption_check()
-    reference = run_terminal(task)
-    if _results_match(result, reference):
+    with spans.at(index, dev), spans.span("sdc_check"):
+      reference = run_terminal(task)
+      matched = _results_match(result, reference)
+    if matched:
       for i, _, r in buf:
         finish_fold(i, r)
       buf.clear()
@@ -654,7 +674,9 @@ def run_fleet(tasks: Iterable[ChunkTask], reducers: Dict[str, object],
         pool.checkin(twin.dev)
       shard.twin = twin.twin = None
     try:
-      result = shard.handle if shard.immediate else shard.handle.resolve()
+      with spans.at(shard.index, shard.dev):
+        result = shard.handle if shard.immediate \
+            else shard.handle.resolve()
     except SweepKilled:
       if shard.dev is not None:
         pool.checkin(shard.dev)
@@ -706,7 +728,6 @@ def run_fleet(tasks: Iterable[ChunkTask], reducers: Dict[str, object],
   meta = {"seconds": seconds, "workers": 1.0,
           "n_chunks": float(counters["n_chunks"]),
           "rows_transferred": float(counters["n_transferred"]),
-          "rows_per_sec": counters["n_rows"] / max(seconds, 1e-12),
           "n_retries": float(n_retries),
           "n_demotions": float(n_demotions),
           "n_resumed_chunks": float(n_resumed),

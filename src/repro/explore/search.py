@@ -546,7 +546,6 @@ def guided_search(space: DesignSpace,
   meta = {"seconds": seconds, "workers": 1.0,
           "n_chunks": float(gens_run),
           "rows_transferred": float(offset),
-          "rows_per_sec": offset / max(seconds, 1e-12),
           "evaluations": float(offset),
           "generations": float(gens_run),
           "population": float(population),

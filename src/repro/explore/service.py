@@ -808,7 +808,6 @@ class ExplorationService:
     meta = {"seconds": seconds, "workers": 1.0,
             "n_chunks": float(s.counters["n_chunks"]),
             "rows_transferred": float(s.counters["n_transferred"]),
-            "rows_per_sec": s.counters["n_rows"] / max(seconds, 1e-12),
             "n_retries": float(n_retries),
             "n_demotions": float(n_demotions),
             "n_resumed_chunks": float(s.n_resumed),

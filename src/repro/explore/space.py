@@ -49,6 +49,7 @@ from repro.core.dataflow import AcceleratorConfig
 from repro.core.pe import PAPER_PE_TYPES
 from repro.core.ppa import HW_RANGES
 from repro.core.table import ConfigTable
+from repro.explore import spans
 
 # canonical axis order == AcceleratorConfig field order == the RNG call
 # order of the legacy sampler (determinism contract, do not reorder)
@@ -429,10 +430,17 @@ class DesignSpace:
                   method: str = "random", chunk_size: int = 65536
                   ) -> Iterator[ConfigTable]:
     """Lazy twin of :meth:`sample_table` (same per-type seed offsets);
-    chunks arrive per PE type, in type order."""
+    chunks arrive per PE type, in type order.  Each step is a ``sample``
+    span (:mod:`repro.explore.spans`)."""
     for i, t in enumerate(self.pe_types):
-      yield from self.iter_type_tables(t, n_per_type, seed=seed + 100 * i,
-                                       method=method, chunk_size=chunk_size)
+      chunks = self.iter_type_tables(t, n_per_type, seed=seed + 100 * i,
+                                     method=method, chunk_size=chunk_size)
+      while True:
+        with spans.span("sample"):
+          chunk = next(chunks, None)
+        if chunk is None:
+          break
+        yield chunk
 
   def _grid_flat_indices(self, n: int, total: int, lo: int, hi: int,
                          prev_last: int) -> np.ndarray:

@@ -325,7 +325,6 @@ def _cached_result(reducers: Dict[str, Reducer], state: Dict[str, object],
       n_rows=n_rows, seconds=seconds,
       meta={"seconds": seconds, "workers": 0.0, "n_chunks": n_chunks,
             "rows_transferred": 0.0,
-            "rows_per_sec": n_rows / max(seconds, 1e-12),
             "n_retries": 0.0, "n_demotions": 0.0,
             "n_resumed_chunks": n_chunks, "n_overflows": 0.0,
             "store_hit": 1.0})

@@ -57,6 +57,7 @@ from typing import (Callable, Dict, Iterable, Iterator, Optional, Sequence,
 
 import numpy as np
 
+from repro.explore import spans
 from repro.explore.frame import (_MAXIMIZE_COLUMNS, ResultFrame, pareto_mask,
                                  stable_topk_indices)
 from repro.explore.resilience import (ChunkError, ChunkTask, ResiliencePolicy,
@@ -480,22 +481,23 @@ def fold_chunk(reducers: Dict[str, Reducer], counters: Dict[str, int],
   """Resolve (if pending) and fold one completed chunk into every
   reducer, updating ``counters``.  Shared by :func:`run_stream` and the
   exploration service's session scheduler so both fold identically."""
-  if hasattr(result, "resolve"):
-    result = result.resolve()
-  counters["n_chunks"] += 1
-  payloads = getattr(result, "payloads", None)
-  if payloads is not None:  # a device FusedChunk (duck-typed: keeps
-    counters["n_rows"] += result.n_rows  # numpy path device-import-free
-    counters["n_transferred"] += result.n_transferred
-    counters["n_overflows"] += getattr(result, "n_overflows", 0)
-    for name, payload in payloads.items():
-      reducers[name].fold_payload(payload)
-    return
-  frame, indices = result
-  counters["n_rows"] += len(frame)
-  counters["n_transferred"] += len(frame)
-  for r in reducers.values():
-    r.fold(frame, indices)
+  with spans.span("fold_chunk"):
+    if hasattr(result, "resolve"):
+      result = result.resolve()
+    counters["n_chunks"] += 1
+    payloads = getattr(result, "payloads", None)
+    if payloads is not None:  # a device FusedChunk (duck-typed: keeps
+      counters["n_rows"] += result.n_rows  # numpy path device-import-free
+      counters["n_transferred"] += result.n_transferred
+      counters["n_overflows"] += getattr(result, "n_overflows", 0)
+      for name, payload in payloads.items():
+        reducers[name].fold_payload(payload)
+      return
+    frame, indices = result
+    counters["n_rows"] += len(frame)
+    counters["n_transferred"] += len(frame)
+    for r in reducers.values():
+      r.fold(frame, indices)
 
 
 # ROB002: every wait in explore/ must carry a bounded timeout (the
@@ -549,6 +551,9 @@ def run_stream(tasks: Iterable[Task], reducers: Dict[str, Reducer],
     speculation, elastic resharding and the silent-corruption sentinel.
     Chunk-partition bit-identity keeps the fronts identical to this
     single-device path.
+
+  ``meta`` also carries the sweep's span counters (see
+  :mod:`repro.explore.spans`).
   """
   if pool is not None:
     from repro.explore.fleet import run_fleet
@@ -556,7 +561,18 @@ def run_stream(tasks: Iterable[Task], reducers: Dict[str, Reducer],
                      dispatch_ahead=dispatch_ahead, resume_from=resume_from,
                      journal_key=journal_key,
                      checkpoint_every=checkpoint_every)
-  workers = max(1, int(workers))
+  with spans.recording() as recorder:
+    with spans.span("stream"):
+      out = _drain(tasks, reducers, recorder, max(1, int(workers)),
+                   dispatch_ahead, policy, resume_from, journal_key,
+                   checkpoint_every)
+    out.meta.update(recorder.meta())
+  return out
+
+
+def _drain(tasks, reducers, recorder, workers, dispatch_ahead, policy,
+           resume_from, journal_key, checkpoint_every) -> StreamResult:
+  """The body of :func:`run_stream` on one device, inside its span."""
   t0 = time.perf_counter()
   journal = None
   done_chunks: set = set()
@@ -610,11 +626,16 @@ def run_stream(tasks: Iterable[Task], reducers: Dict[str, Reducer],
 
   def finish(index, result) -> None:
     try:
-      fold_chunk(reducers, counters, result)
+      with spans.at(index):
+        fold_chunk(reducers, counters, result)
     except Exception as e:
       fail(index, e)
     done_chunks.add(index)
     checkpoint()
+
+  def execute_at(index, task):
+    with spans.at(index):
+      return execute(task)
 
   def indexed(ts) -> Iterator[Tuple[int, Task]]:
     """(global chunk index, task) pairs, skipping already-folded chunks
@@ -629,7 +650,7 @@ def run_stream(tasks: Iterable[Task], reducers: Dict[str, Reducer],
     window: "deque" = deque()
     for index, task in indexed(tasks):
       try:
-        res = execute(task)
+        res = execute_at(index, task)
       except Exception as e:
         fail(index, e)
       if hasattr(res, "resolve"):
@@ -643,6 +664,7 @@ def run_stream(tasks: Iterable[Task], reducers: Dict[str, Reducer],
   else:
     with ThreadPoolExecutor(max_workers=workers) as pool:
       pending: "deque" = deque()  # (future, global chunk index), FIFO
+      on_worker = spans.bind(recorder, execute_at)
 
       def drain_oldest() -> None:
         fut, index = pending[0]
@@ -658,7 +680,7 @@ def run_stream(tasks: Iterable[Task], reducers: Dict[str, Reducer],
 
       try:
         for index, task in indexed(tasks):
-          pending.append((pool.submit(execute, task), index))
+          pending.append((pool.submit(on_worker, index, task), index))
           while len(pending) >= 2 * workers:
             drain_oldest()
         while pending:
@@ -675,7 +697,6 @@ def run_stream(tasks: Iterable[Task], reducers: Dict[str, Reducer],
   meta = {"seconds": seconds, "workers": float(workers),
           "n_chunks": float(counters["n_chunks"]),
           "rows_transferred": float(counters["n_transferred"]),
-          "rows_per_sec": counters["n_rows"] / max(seconds, 1e-12),
           "n_retries": float(n_retries),
           "n_demotions": float(n_demotions),
           "n_resumed_chunks": float(n_resumed),
@@ -752,7 +773,8 @@ def explore_tasks(backend, space: DesignSpace, layers, network: str,
       and hasattr(backend, "fused_eval_pending")
   if device_mode:
     from repro.explore.device import build_plan
-    plan = build_plan(reducers, joint=False)
+    with spans.span("plan"):
+      plan = build_plan(reducers, joint=False)
   # the terminal numpy rung: bypasses jit even on a device backend
   host_eval = getattr(backend, "host_evaluate_table", None)
   if host_eval is None:
@@ -805,19 +827,20 @@ def co_explore_tasks(backend, space: DesignSpace, arch_accs,
                      "streaming requires the joint columnar path")
   archs = tuple(arch for arch, _ in arch_accs)
   accs = np.asarray([float(acc) for _, acc in arch_accs], np.float64)
-  stack = LayerStack.from_layer_lists(
-      [arch_to_layers(a, image_size=image_size) for a in archs])
   plan = None
   device_mode = getattr(backend, "jit", False) \
       and hasattr(backend, "fused_co_eval_pending")
   dedup = None
-  if device_mode:
-    from repro.explore.device import build_plan
-    plan = build_plan(reducers, joint=True)
-    # one global distinct-layer factorization: every block slices the
-    # same unique rows, so one compiled program serves the whole sweep
-    unique_cols, slot_ids = stack.dedup_slots()
-    dedup = lambda a_sl: (unique_cols, slot_ids[a_sl])  # noqa: E731
+  with spans.span("plan"):
+    stack = LayerStack.from_layer_lists(
+        [arch_to_layers(a, image_size=image_size) for a in archs])
+    if device_mode:
+      from repro.explore.device import build_plan
+      plan = build_plan(reducers, joint=True)
+      # one global distinct-layer factorization: every block slices the
+      # same unique rows, so one compiled program serves the whole sweep
+      unique_cols, slot_ids = stack.dedup_slots()
+      dedup = lambda a_sl: (unique_cols, slot_ids[a_sl])  # noqa: E731
   # the terminal numpy rung: bypasses jit even on a device backend
   host_co = getattr(backend, "host_co_evaluate_table", None)
   if host_co is None:
@@ -854,14 +877,17 @@ def co_explore_tasks(backend, space: DesignSpace, arch_accs,
     offset = 0
     ci = 0
     for ti, pe_type in enumerate(space.pe_types):
-      hw = space.sample_type_table(pe_type, n_hw_per_type,
-                                   seed=seed + 17 * ti, method=method)
-      joint = hw.cross(stack.n_archs)
+      with spans.span("sample"):
+        hw = space.sample_type_table(pe_type, n_hw_per_type,
+                                     seed=seed + 17 * ti, method=method)
+        joint = hw.cross(stack.n_archs)
       for a_sl, h_sl in joint.block_slices(chunk_size):
-        idx = offset + joint.block_indices(a_sl, h_sl)
-        yield make_task(hw.select(h_sl),
-                        stack.slice_archs(a_sl.start, a_sl.stop),
-                        a_sl, idx, ci)
+        with spans.span("sample"):
+          idx = offset + joint.block_indices(a_sl, h_sl)
+          task = make_task(hw.select(h_sl),
+                           stack.slice_archs(a_sl.start, a_sl.stop),
+                           a_sl, idx, ci)
+        yield task
         ci += 1
       offset += len(joint)
 
@@ -901,19 +927,20 @@ def stream_explore(backend, space: DesignSpace, layers, network: str = "net",
   """
   if reducers is None:
     reducers = default_explore_reducers()
-  tasks = explore_tasks(backend, space, layers, network, n_per_type, seed,
-                        method, chunk_size, reducers)
-  key = ""
-  if resume_from is not None:
-    key = explore_sweep_key(space, reducers, n_per_type=n_per_type,
-                            seed=seed, method=method, chunk_size=chunk_size,
-                            network=network)
-  return run_stream(tasks, reducers,
-                    workers=default_workers(backend) if workers is None
-                    else workers,
-                    policy=policy, resume_from=resume_from,
-                    journal_key=key, checkpoint_every=checkpoint_every,
-                    pool=pool)
+  with spans.recording():
+    tasks = explore_tasks(backend, space, layers, network, n_per_type, seed,
+                          method, chunk_size, reducers)
+    key = ""
+    if resume_from is not None:
+      key = explore_sweep_key(space, reducers, n_per_type=n_per_type,
+                              seed=seed, method=method,
+                              chunk_size=chunk_size, network=network)
+    return run_stream(tasks, reducers,
+                      workers=default_workers(backend) if workers is None
+                      else workers,
+                      policy=policy, resume_from=resume_from,
+                      journal_key=key, checkpoint_every=checkpoint_every,
+                      pool=pool)
 
 
 def stream_co_explore(backend, space: DesignSpace, arch_accs,
@@ -937,17 +964,18 @@ def stream_co_explore(backend, space: DesignSpace, arch_accs,
   """
   if reducers is None:
     reducers = default_co_reducers()
-  tasks = co_explore_tasks(backend, space, arch_accs, n_hw_per_type, seed,
-                           image_size, method, chunk_size, reducers)
-  key = ""
-  if resume_from is not None:
-    key = co_explore_sweep_key(space, reducers, arch_accs,
-                               n_hw_per_type=n_hw_per_type, seed=seed,
-                               image_size=image_size, method=method,
-                               chunk_size=chunk_size)
-  return run_stream(tasks, reducers,
-                    workers=default_workers(backend) if workers is None
-                    else workers,
-                    policy=policy, resume_from=resume_from,
-                    journal_key=key, checkpoint_every=checkpoint_every,
-                    pool=pool)
+  with spans.recording():
+    tasks = co_explore_tasks(backend, space, arch_accs, n_hw_per_type, seed,
+                             image_size, method, chunk_size, reducers)
+    key = ""
+    if resume_from is not None:
+      key = co_explore_sweep_key(space, reducers, arch_accs,
+                                 n_hw_per_type=n_hw_per_type, seed=seed,
+                                 image_size=image_size, method=method,
+                                 chunk_size=chunk_size)
+    return run_stream(tasks, reducers,
+                      workers=default_workers(backend) if workers is None
+                      else workers,
+                      policy=policy, resume_from=resume_from,
+                      journal_key=key, checkpoint_every=checkpoint_every,
+                      pool=pool)
