@@ -45,7 +45,7 @@ fit-once / evaluate-many DSE and HW x NN co-exploration:
   device programs      the ``VectorOracleBackend(jit=True)`` streaming
                        path: exact x64 evaluation bit-identical to numpy,
                        fused on-device pareto/top-k/stats reduction with
-                       O(survivors) transfer, async dispatch-ahead
+                       O(cap) transfer, async dispatch-ahead
                        (imported lazily — see note below)        [device]
   resilience           fault-tolerant sweeps: chunk retry (RetryPolicy),
                        graceful device->host degradation + watchdog

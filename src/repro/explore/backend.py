@@ -169,8 +169,8 @@ class VectorOracleBackend:
   The streaming engine additionally uses the ``*_pending`` entry points:
   chunks dispatch asynchronously (jax futures) and resolve later, and
   with a :class:`repro.explore.device.DevicePlan` the whole
-  evaluate+reduce pipeline is fused on device so only O(survivors)
-  floats come back per chunk.
+  evaluate+reduce pipeline is fused on device so only O(cap) floats
+  come back per chunk, cut to the survivors on the host.
   """
   name = "vector-oracle"
   prefers_table = True
@@ -528,7 +528,7 @@ class VectorOracleBackend:
                          plan, idx: np.ndarray):
     """Dispatch one fused evaluate+reduce chunk (see
     :mod:`repro.explore.device`); resolves to per-reducer payloads with
-    O(survivors) device->host transfer."""
+    O(cap) device->host transfer, cut on the host."""
     from repro.explore import device as device_lib
     layers = tuple(layers)
     with spans.span("dispatch"):

@@ -4,7 +4,8 @@ The host streaming path (repro.explore.streaming) evaluates a chunk,
 copies full latency/power/area arrays device->host (or allocates them on
 host), and reduces in numpy.  This module moves the whole
 evaluate -> derive-columns -> reduce pipeline into one jitted x64 program
-per chunk so that only O(survivors) floats cross the device boundary:
+per chunk so that only O(cap) floats cross the device boundary, the
+plan's fixed survivor capacity, cut to each count on the host:
 
   pareto    an exact-superset non-dominated prefilter on device (grouped
             2-D staircase elimination when the objectives allow it, the
@@ -519,7 +520,8 @@ class FusedChunk:
   """Resolved fused-chunk result: one payload per reducer (see
   ``Reducer.fold_payload``) plus row counts for engine accounting —
   ``n_transferred`` is how many evaluated rows actually crossed the
-  device boundary (the O(survivors), not O(chunk_size), evidence);
+  device boundary (the O(cap), cut on the host, not O(chunk_size),
+  evidence: ``cap`` rows per in-cap pareto reducer, ``k`` per top-k);
   ``n_overflows`` counts pareto reducers whose survivor count blew the
   plan cap and fell back to the full chunk frame — the first rung of
   the graceful-degradation story (see repro.explore.resilience)."""
@@ -619,9 +621,12 @@ class PendingFused(_PendingBase):
   # -- resolution -----------------------------------------------------------
 
   def resolve(self) -> FusedChunk:
-    """Wait for the program, slice each pareto reducer's survivors on the
-    device, fetch what every reducer needs, and build its payload: one
-    span per phase, around the loop over reducers."""
+    """Wait for the program, fetch what every reducer needs (each in-cap
+    pareto reducer's survivors whole, at the plan's fixed ``cap``), cut
+    those survivors to their count on the host, and build each payload:
+    one span per phase, around the loop over reducers.  Cutting on the
+    host rather than slicing device arrays keeps every survivor count
+    from compiling a slice program of its own."""
     with spans.span("resolve") as resolving:
       with spans.span("wait"):  # the first blocking read
         counts = {name: int(self._reduced[name]["count"])
@@ -629,28 +634,29 @@ class PendingFused(_PendingBase):
                   if isinstance(spec, ParetoSpec)}
       resolving.note(survivors=sum(counts.values()))
       overflows = sum(c > self.plan.cap for c in counts.values())
-      with spans.span("slice"):
-        sliced = {name: (self._reduced[name]["idx"][:c],
-                         [r[:c] for r in self._reduced[name]["rows"]])
-                  for name, c in counts.items() if c <= self.plan.cap}
       with spans.span("fetch"):
         # rare: a count over the cap folds the full chunk instead
         full = self.full_frame() if overflows else None
-        host = {name: (np.asarray(idx, np.int64),
-                       [np.asarray(r, np.float64) for r in rows])
-                for name, (idx, rows) in sliced.items()}
+        transferred = len(self.indices) if overflows else 0
+        host = {}
         for name, spec in self.plan:
           out = self._reduced[name]
-          if isinstance(spec, TopKSpec):
+          if isinstance(spec, TopKSpec) or (
+              isinstance(spec, ParetoSpec) and counts[name] <= self.plan.cap):
             host[name] = (np.asarray(out["idx"], np.int64),
                           [np.asarray(r, np.float64) for r in out["rows"]])
+            transferred += host[name][0].size
           elif isinstance(spec, StatsSpec):
             host[name] = {k: float(out[k]) if k != "n" else int(out[k])
                           for k in out}
           elif isinstance(spec, HistSpec):
             host[name] = np.asarray(out["counts"], np.int64)
+      with spans.span("slice"):  # the padded tail past the count is unread
+        for name, c in counts.items():
+          if name in host:
+            local, rows = host[name]
+            host[name] = (local[:c], [r[:c] for r in rows])
       payloads: Dict[str, tuple] = {}
-      transferred = len(self.indices) if overflows else 0
       for name, spec in self.plan:
         if isinstance(spec, StatsSpec):
           payloads[name] = ("stats", host[name])
@@ -660,7 +666,6 @@ class PendingFused(_PendingBase):
           payloads[name] = ("rows",) + full
         else:
           local, rows = host[name]
-          transferred += local.size
           payloads[name] = ("rows", self._mini_frame(local, rows),
                             self.indices[local])
       return FusedChunk(payloads=payloads, n_rows=len(self.indices),

@@ -28,7 +28,7 @@ On a ``VectorOracleBackend(jit=True)`` the streaming engine goes
 device-resident: exact x64 evaluation under ``jax.jit`` (bit-identical
 to the numpy path), asynchronous dispatch-ahead chunk scheduling, and —
 when every reducer is device-fusable — fused on-device reduction so
-only O(survivors) floats come back per chunk
+only O(cap) floats come back per chunk, cut on the host
 (:mod:`repro.explore.device`).
 """
 from __future__ import annotations

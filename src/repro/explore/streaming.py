@@ -914,8 +914,9 @@ def stream_explore(backend, space: DesignSpace, layers, network: str = "net",
   On a ``jit=True`` backend chunks dispatch asynchronously; when every
   reducer is device-fusable the evaluate+reduce pipeline additionally
   fuses into one jitted program per chunk (see
-  :mod:`repro.explore.device`), so only O(survivors) floats come back
-  per chunk instead of full metric arrays.
+  :mod:`repro.explore.device`), so only O(cap) floats come back per
+  chunk, cut to the survivors on the host, instead of full metric
+  arrays.
 
   Each chunk carries the full fallback ladder ``fused-device ->
   unfused-device -> numpy`` (whichever rungs the backend supports); a

@@ -231,6 +231,42 @@ class TestFusedReducers:
     for col in METRICS:
       assert np.array_equal(got.column(col), want.column(col)), col
 
+  @pytest.mark.parametrize("case", ["one", "cap", "over"])
+  def test_host_cut_at_its_edges(self, layers, space, case):
+    """Survivors come back at the plan's cap and are cut to their count
+    on the host: a count of 1, a count of exactly ``cap`` and one of
+    ``cap + 1`` (the full-chunk fallback) each fold to the numpy
+    one-shot front, bit for bit, and count the rows that crossed."""
+    from repro.explore import device as device_lib
+    backend = VectorOracleBackend(jit=True)
+    cols = ("latency_s", "power_mw", "area_mm2")
+    tbl = space.sample_table(60, seed=6)
+    if case == "one":
+      tbl = tbl.select(slice(0, 1))
+    idx = np.arange(len(tbl), dtype=np.int64)
+
+    def resolved(cap):
+      reducers = {"pareto": ParetoAccumulator(cols)}
+      plan = device_lib.build_plan(reducers, joint=False, cap=cap)
+      pend = backend.fused_eval_pending(tbl, layers, "net", plan, idx)
+      return pend.resolve(), reducers["pareto"]
+
+    probe, _ = resolved(len(tbl))  # no count can pass the chunk's length
+    count = len(probe.payloads["pareto"][1])
+    assert (count == 1) == (case == "one")
+    cap = {"one": 4, "cap": count, "over": count - 1}[case]
+    chunk, acc = resolved(cap)
+    over = case == "over"
+    assert chunk.n_overflows == int(over)
+    assert chunk.n_transferred == (len(tbl) if over else cap)
+    kind, frame, _ = chunk.payloads["pareto"]
+    assert kind == "rows" and len(frame) == (len(tbl) if over else count)
+    acc.fold_payload(chunk.payloads["pareto"])
+    base = VectorOracleBackend().evaluate_table(tbl, layers)
+    mask = base.pareto(cols)
+    _assert_frames_equal(acc.result(), base.select(mask), case)
+    assert np.array_equal(acc.indices, np.flatnonzero(mask))
+
   def test_collect_reducer_is_not_fusable(self):
     from repro.explore.device import build_plan
     from repro.explore.streaming import CollectAccumulator
@@ -341,7 +377,7 @@ class TestInterleavedSearchGenerations:
         assert len(frame) == len(tbl)  # full-chunk fallback
       else:
         fused_hit = True
-        assert len(frame) <= cap       # O(survivors) transfer
+        assert len(frame) <= cap       # cut to the survivors on the host
       reducers["pareto"].fold_payload(chunk.payloads["pareto"])
       got = reducers["pareto"].result()
       for col in METRICS:

@@ -5,8 +5,8 @@
   * a streamed sweep's ``meta`` carries every span's self time, and the
     self times inside the ``stream`` span add up to the sweep's seconds;
     the spans change no front;
-  * compiles are credited to the span that makes them: a survivor count
-    new to the process compiles slices, a repeated one does not.
+  * compiles are credited to the span that makes them: survivors are cut
+    on the host, so a survivor count new to the process compiles nothing.
 """
 import threading
 
@@ -16,9 +16,11 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from repro.core.cnn import SEARCH_SPACE, ArchChoice
+from repro.core.dataflow import LayerStack
+from repro.core.supernet import arch_to_layers
 from repro.core.workloads import get_network
 from repro.explore import DesignSpace, VectorOracleBackend, spans
-from repro.explore.device import DevicePlan, ParetoSpec
+from repro.explore.device import DevicePlan, ParetoSpec, build_plan
 from repro.explore.streaming import (ParetoAccumulator, TopKAccumulator,
                                      stream_co_explore, stream_explore)
 
@@ -162,29 +164,45 @@ def test_sweep_meta_carries_every_span(kind, layers, arch_accs):
   assert host["self_s_stream"] > 0.0
 
 
-def test_new_survivor_counts_compile_slices_once(layers):
+@pytest.mark.parametrize("kind", ["plain", "joint"])
+def test_new_survivor_counts_compile_slices_once(kind, layers, arch_accs):
+  """Survivors come back at the plan's fixed capacity and are cut on the
+  host: once the fused program is compiled, a survivor count new to the
+  process compiles nothing under ``slice``, nor anywhere else."""
   backend = VectorOracleBackend(jit=True)
-  # a survivor capacity no other test uses: these slice shapes are new
-  plan = DevicePlan(specs=(("pareto", ParetoSpec(
-      ("perf_per_area", "energy_mj"), ("perf_per_area",))),), cap=1237)
-  table = DesignSpace().sample_table(40, seed=29)
-  idx = np.arange(len(table), dtype=np.int64)
+  cols = ("perf_per_area", "energy_mj") if kind == "plain" \
+      else ("top1_err", "energy_mj", "area_mm2")
+  # survivor capacities no other test uses: these shapes are new
+  plan = build_plan({"pareto": ParetoAccumulator(cols)},
+                    joint=kind == "joint",
+                    cap=1237 if kind == "plain" else 1239)
+  archs = tuple(a for a, _ in arch_accs)
+  accs = np.asarray([acc for _, acc in arch_accs], np.float64)
+  stack = LayerStack.from_layer_lists(
+      [arch_to_layers(a, image_size=16) for a in archs])
 
-  def resolved():
+  def pending(seed):
+    table = DesignSpace().sample_table(10, seed=seed)
+    if kind == "plain":
+      return backend.fused_eval_pending(
+          table, layers, "net", plan, np.arange(len(table), dtype=np.int64))
+    return backend.fused_co_eval_pending(
+        table, stack, "net", plan,
+        np.arange(len(archs) * len(table), dtype=np.int64), 0, accs, archs)
+
+  def resolved(seed):
     with spans.recording() as rec:
       with spans.span("stream"):
-        chunk = backend.fused_eval_pending(table, layers, "net", plan,
-                                           idx).resolve()
+        chunk = pending(seed).resolve()
     return chunk, rec
 
-  first, rec = resolved()
-  assert first.n_transferred > 0
-  assert rec.n_compiles["slice"] >= 1
-  assert rec.compile_s["slice"] > 0.0
-  again, rec = resolved()  # the same survivor count: nothing compiles
-  assert again.n_transferred == first.n_transferred
+  first, rec = resolved(29)  # compiles the fused program, and no slice
+  assert rec.n_compiles["slice"] == 0
+  again, rec = resolved(30)  # another survivor count: nothing compiles
+  assert len(again.payloads["pareto"][1]) != len(first.payloads["pareto"][1])
   assert rec.n_compiles["slice"] == 0
   assert sum(rec.n_compiles.values()) == 0
+  assert first.n_transferred == again.n_transferred == plan.cap
 
 
 def test_compile_totals_count_the_process(layers):
