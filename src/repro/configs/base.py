@@ -42,6 +42,20 @@ class ModelConfig:
   moe_offset: int = 0                  # ... == offset (when n_experts > 0)
   capacity_factor: float = 1.25
   moe_group_size: int = 512
+  first_dense_layers: int = 0          # leading dense layers before MoE
+  # synthetic routing of the PPA lowering (repro.core.workloads
+  # .from_config), assumed, not published: expert popularity falls as
+  # rank ** -router_skew over a seeded permutation of the experts
+  router_skew: float = 0.0
+  router_seed: int = 0
+
+  # multi-head latent attention (MLA): keys and values cached as one
+  # kv_lora_rank-wide latent plus a shared qk_rope_head_dim-wide rope key;
+  # head_dim is the no-rope query/key width (qk_nope_head_dim)
+  kv_lora_rank: int = 0                # 0 => ordinary multi-head attention
+  q_lora_rank: int = 0                 # 0 => queries not compressed
+  qk_rope_head_dim: int = 0
+  v_head_dim: int = 0
 
   # hybrid / ssm
   attn_period: int = 0                 # jamba: 1 attn per this many layers

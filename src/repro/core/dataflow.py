@@ -24,10 +24,16 @@ Mapping summary (Chen et al., ISCA'16):
   * passes iterate over ceil(C / C_tile) * ceil(F / F_tile) tiles; psums
     spill to the global buffer between channel tiles.
 
+Matrix products (:class:`GemmLayer`) have a row-stationary mapping of
+their own, written out in that class's docstring: the rows of the left
+operand take the place of output rows, with a kernel one wide.
+
 Each simulation entry point has a vectorized ``*_batch`` sibling
 (:func:`simulate_layer_batch`, :func:`simulate_network_batch`) that
 evaluates a whole :class:`repro.core.table.ConfigTable` column-at-a-time,
 bit-identically to the scalar model on the numpy path.
+:func:`simulate_network_table` evaluates a merged layer table (every
+entry once, weighted by its count) with the layer features as arrays.
 
 For joint HW x NN co-exploration the per-network layer loop additionally
 batches over *architectures*: :class:`LayerStack` pre-packs every
@@ -97,6 +103,80 @@ class ConvLayer:
             float(self.S), float(self.P), float(self.rs), float(self.ds))
 
 
+OPERANDS = ("weight", "cache")
+
+
+@dataclasses.dataclass(frozen=True)
+class GemmLayer:
+  """``groups`` independent products (M, K) @ (K, N), run one after
+  another, standing for ``count`` identical layers.
+
+  The left operand (M x K) is an activation.  The right operand (K x N)
+  is either a weight (``operand="weight"``) or an activation read back
+  from memory, such as a sequence's KV cache (``operand="cache"``).
+  Each group has a right operand of its own: one head's absorbed
+  projection, or one sequence's cache.
+
+  Row-stationary mapping of one group (the conv mapping with the M rows
+  of the left operand as output rows, each one wide, and a kernel one
+  wide; R x Cl PEs, scratchpads sp_if / sp_fw / sp_ps words):
+
+    col_folds = ceil(M / Cl)            M rows over the PE columns
+    cols_used = min(M, Cl)
+    sets      = R                       every PE row a (K, N) tile
+    f_tile    = max(1, min(N, sp_ps))   N tiled by the psum spad
+    k_tile    = max(1, min(K, sp_fw // f_tile, sp_if * sets))
+                                        K tiled by the filter and
+                                        ifmap spads
+    passes    = ceil(ceil(K / k_tile) / sets) * ceil(N / f_tile)
+                * col_folds
+    compute   = max(passes * (k_tile * f_tile + 1 + cols_used),
+                    M K N / (R Cl))
+
+  DRAM words: the left operand M K once if it fits half the global
+  buffer at ``act_bits``, else once per N tile; the right operand K N
+  once if it fits a quarter of the buffer at its own bits, else once per
+  column fold; the output M N once at ``psum_bits``.  A ``cache``
+  operand moves at ``act_bits`` through the scratchpads, the buffer and
+  DRAM (``weight_bits`` for a weight), and its fit test is per group, so
+  a batch's caches never share the buffer.  The stall past 85% overlap
+  and every access count follow the conv model.  One instance's cycles,
+  accesses and MACs are ``groups`` times one group's; a network counts
+  its latency, energy and utilization ``count`` times.
+  """
+  name: str
+  M: int
+  K: int
+  N: int
+  groups: int = 1
+  operand: str = "weight"
+  count: int = 1
+
+  def __post_init__(self):
+    if self.operand not in OPERANDS:
+      raise ValueError(f"operand must be one of {OPERANDS}, got "
+                       f"{self.operand!r}")
+    for field in ("M", "K", "N", "groups", "count"):
+      if int(getattr(self, field)) < 1:
+        raise ValueError(f"{field} must be at least 1, got "
+                         f"{getattr(self, field)}")
+
+  @property
+  def shape(self) -> Tuple[int, int, int, int, str]:
+    """What identical layers share, whatever their names and counts."""
+    return (self.M, self.K, self.N, self.groups, self.operand)
+
+  @property
+  def macs(self) -> int:
+    """MACs of one instance (every group)."""
+    return self.groups * self.M * self.K * self.N
+
+
+def layer_count(layer) -> int:
+  """How many identical layers one entry stands for."""
+  return getattr(layer, "count", 1)
+
+
 @dataclasses.dataclass(frozen=True)
 class AcceleratorConfig:
   """The hardware half of QUIDAM's input space (Fig. 2)."""
@@ -143,9 +223,82 @@ class LayerStats:
   dram_writes: float
 
 
+def _right_bits(pe: pe_lib.PEType, layer) -> int:
+  """Bits a word of the right operand (weights, or a cache) moves at."""
+  if getattr(layer, "operand", "weight") == "cache":
+    return pe.act_bits
+  return pe.weight_bits
+
+
+def _simulate_gemm(cfg: AcceleratorConfig, layer: GemmLayer,
+                   clock_mhz: float) -> LayerStats:
+  """One GemmLayer instance, by the mapping in its docstring."""
+  pe = cfg.pe
+  M, K, N, g = layer.M, layer.K, layer.N, layer.groups
+  right_bits = _right_bits(pe, layer)
+
+  # ---- spatial mapping: M rows over the columns, every row a set --------
+  col_folds = math.ceil(M / cfg.pe_cols)
+  cols_used = min(M, cfg.pe_cols)
+  sets = cfg.pe_rows
+  spatial_util = (sets * cols_used) / cfg.n_pe
+
+  # ---- scratchpad-bounded tiling ----------------------------------------
+  f_tile = max(1, min(N, cfg.sp_ps))
+  k_tile = max(1, min(K, cfg.sp_fw // f_tile))
+  k_tile = max(1, min(k_tile, max(cfg.sp_if, 1) * sets))
+  n_k_passes_eff = math.ceil(math.ceil(K / k_tile) / sets)
+  n_f_passes = math.ceil(N / f_tile)
+  passes = n_k_passes_eff * n_f_passes * col_folds
+
+  # ---- compute cycles of one group ---------------------------------------
+  macs = M * K * N
+  compute_cycles = passes * (k_tile * f_tile + (1 + cols_used))
+  ideal_cycles = macs / cfg.n_pe
+  compute_cycles = max(compute_cycles, ideal_cycles)
+  utilization = min(1.0, ideal_cycles / max(compute_cycles, 1.0)) \
+      * min(1.0, spatial_util + 1e-9)
+
+  # ---- access counts of one group ----------------------------------------
+  spad_reads = 3.0 * macs
+  spad_writes = float(macs)
+  gbuf_bits = cfg.gbuf_kb * 1024 * 8
+  left_words = M * K
+  left_fits = left_words * pe.act_bits <= 0.5 * gbuf_bits
+  dram_if = left_words * (1 if left_fits else n_f_passes)
+  right_words = K * N
+  right_fits = right_words * right_bits <= 0.25 * gbuf_bits
+  dram_w = right_words * (1 if right_fits else col_folds)
+  of_words = M * N
+  psum_spills = max(n_k_passes_eff - 1, 0)
+  gbuf_reads = left_words * n_f_passes + right_words * col_folds \
+      + of_words * psum_spills
+  gbuf_writes = of_words * (psum_spills + 1.0)
+
+  # ---- bandwidth bound ---------------------------------------------------
+  cycle_s = 1e-6 / clock_mhz
+  dram_bits = (dram_if * pe.act_bits + dram_w * right_bits
+               + of_words * pe.psum_bits)
+  dram_cycles = dram_bits / 8.0 / (cfg.bandwidth_gbps * 1e9) / cycle_s
+  dram_stall = max(0.0, dram_cycles - 0.85 * compute_cycles)
+  cycles = compute_cycles + dram_stall
+
+  # ---- groups run one after another -------------------------------------
+  return LayerStats(
+      cycles=cycles * g, compute_cycles=compute_cycles * g,
+      dram_stall_cycles=dram_stall * g, utilization=utilization,
+      macs=layer.macs, spad_reads=spad_reads * g,
+      spad_writes=spad_writes * g, gbuf_reads=gbuf_reads * g,
+      gbuf_writes=gbuf_writes * g, dram_reads=float((dram_if + dram_w) * g),
+      dram_writes=float(of_words * g))
+
+
 def simulate_layer(cfg: AcceleratorConfig, layer: ConvLayer,
                    clock_mhz: float) -> LayerStats:
-  """Cycle-approximate RS dataflow simulation of one layer."""
+  """Cycle-approximate RS dataflow simulation of one layer (one instance
+  of a :class:`GemmLayer`)."""
+  if isinstance(layer, GemmLayer):
+    return _simulate_gemm(cfg, layer, clock_mhz)
   pe = cfg.pe
   E = max(layer.out_dim, 1)
   K, C, F = layer.K, layer.C, layer.F
@@ -237,18 +390,19 @@ def layer_energy_pj(cfg: AcceleratorConfig, layer: ConvLayer,
   """Eyeriss-style hierarchical energy model (pJ) for one layer."""
   pe = cfg.pe
   e = pe_lib.ENERGY_PJ
+  right_bits = _right_bits(pe, layer)
   mac_e = stats.macs * pe.mac_energy_pj
   # scratchpad word widths differ per operand; use the mean of act/weight/
   # psum widths for reads (2 operand reads + 1 psum read) and psum for writes
-  k = max(layer.K, 1)
-  spad_read_bits = stats.macs * (pe.act_bits + pe.weight_bits
+  k = 1 if isinstance(layer, GemmLayer) else max(layer.K, 1)
+  spad_read_bits = stats.macs * (pe.act_bits + right_bits
                                  + pe.psum_bits / k)
   spad_write_bits = stats.spad_writes * pe.psum_bits
   spad_e = (spad_read_bits + spad_write_bits) * e["spad_access_per_bit"]
   gbuf_bits = (stats.gbuf_reads + stats.gbuf_writes) * (
-      (pe.act_bits + pe.weight_bits + pe.psum_bits) / 3.0)
+      (pe.act_bits + right_bits + pe.psum_bits) / 3.0)
   gbuf_e = gbuf_bits * e["gbuf_access_per_bit"]
-  dram_bits = (stats.dram_reads * (pe.act_bits + pe.weight_bits) / 2.0
+  dram_bits = (stats.dram_reads * (pe.act_bits + right_bits) / 2.0
                + stats.dram_writes * pe.psum_bits)
   dram_e = dram_bits * e["dram_access_per_bit"]
   time_s = stats.cycles / (clock_mhz * 1e6)
@@ -259,14 +413,18 @@ def layer_energy_pj(cfg: AcceleratorConfig, layer: ConvLayer,
 def simulate_network(cfg: AcceleratorConfig, layers: Sequence[ConvLayer],
                      clock_mhz: float, leakage_mw: float
                      ) -> Tuple[float, float, List[LayerStats]]:
-  """Returns (total_latency_s, total_energy_mj, per-layer stats)."""
+  """Returns (total_latency_s, total_energy_mj, per-layer stats): one
+  instance's stats per entry, its latency and energy counted ``count``
+  times."""
   total_cycles = 0.0
   total_energy_pj = 0.0
   all_stats: List[LayerStats] = []
   for layer in layers:
     st = simulate_layer(cfg, layer, clock_mhz)
-    total_cycles += st.cycles
-    total_energy_pj += layer_energy_pj(cfg, layer, st, clock_mhz, leakage_mw)
+    n = layer_count(layer)
+    total_cycles += n * st.cycles
+    total_energy_pj += n * layer_energy_pj(cfg, layer, st, clock_mhz,
+                                           leakage_mw)
     all_stats.append(st)
   latency_s = total_cycles / (clock_mhz * 1e6)
   return latency_s, total_energy_pj * 1e-9, all_stats  # pJ -> mJ
@@ -325,7 +483,21 @@ class LayerStatsBatch:
 def _layer_feats(layer: ConvLayer) -> Dict[str, float]:
   """The layer-side constants the batch formulas consume, as Python
   floats (one ConvLayer) — :class:`LayerStack` supplies the same keys as
-  broadcastable ``(n_archs, 1)`` arrays."""
+  broadcastable ``(n_archs, 1)`` arrays.  A :class:`GemmLayer` adds the
+  GEMM-only keys ``E_row`` (an output row's width), ``groups`` and
+  ``cache`` (1.0 for a cache operand); a conv layer has none of them, so
+  its formulas trace exactly as before."""
+  if isinstance(layer, GemmLayer):
+    return {
+        "E": float(layer.M), "E_row": 1.0, "K": 1.0,
+        "C": float(layer.K), "F": float(layer.N),
+        "macs": float(layer.M * layer.K * layer.N),
+        "ifmap_words": float(layer.M * layer.K),
+        "weight_words": float(layer.K * layer.N),
+        "of_words": float(layer.M * layer.N),
+        "groups": float(layer.groups),
+        "cache": 1.0 if layer.operand == "cache" else 0.0,
+    }
   return {
       "E": float(max(layer.out_dim, 1)),
       "K": float(layer.K), "C": float(layer.C), "F": float(layer.F),
@@ -336,14 +508,26 @@ def _layer_feats(layer: ConvLayer) -> Dict[str, float]:
   }
 
 
+def _right_bits_feats(c, f, xp):
+  """Bits of the right operand per (layer, design point): weight bits,
+  or activation bits for a cache operand.  Layers without a ``cache``
+  feature (every conv layer) take ``weight_bits`` at trace time."""
+  if "cache" not in f:
+    return c["weight_bits"]
+  return xp.where(f["cache"] > 0.0, c["act_bits"], c["weight_bits"])
+
+
 def _simulate_layer_feats(c, f, clock_mhz, xp) -> LayerStatsBatch:
   """The batch RS-dataflow formulas over HW columns ``c`` x layer
   features ``f``.  ``f`` values are floats (one layer) or ``(n_archs, 1)``
-  arrays (a LayerStack slot, broadcasting against ``(n_hw,)`` columns to
-  ``(n_archs, n_hw)``); the elementwise op sequence is identical either
-  way, so the numpy path matches the scalar model bit for bit."""
+  arrays (a LayerStack slot or a layer table, broadcasting against
+  ``(n_hw,)`` columns to ``(n_archs, n_hw)``); the elementwise op sequence
+  is identical either way, so the numpy path matches the scalar model bit
+  for bit.  The GEMM-only features (``E_row``, ``groups``, ``cache``)
+  enter only where ``f`` has them."""
   pe_rows, pe_cols, n_pe = c["pe_rows"], c["pe_cols"], c["n_pe"]
   E, K, C, F = f["E"], f["K"], f["C"], f["F"]
+  right_bits = _right_bits_feats(c, f, xp)
   k_safe = xp.maximum(K, 1.0)
 
   # ---- spatial mapping -------------------------------------------------
@@ -369,7 +553,7 @@ def _simulate_layer_feats(c, f, clock_mhz, xp) -> LayerStatsBatch:
   passes = n_c_passes_eff * n_f_passes * col_folds * row_folds
 
   # ---- compute cycles ----------------------------------------------------
-  per_pass = E * K * c_tile * f_tile + (K + cols_used)
+  per_pass = f.get("E_row", E) * K * c_tile * f_tile + (K + cols_used)
   compute_cycles = passes * per_pass
   ideal_cycles = f["macs"] / n_pe
   compute_cycles = xp.maximum(compute_cycles, ideal_cycles)
@@ -386,7 +570,7 @@ def _simulate_layer_feats(c, f, clock_mhz, xp) -> LayerStatsBatch:
   dram_if = ifmap_words * xp.where(ifmap_fits, 1.0, n_f_passes)
   gbuf_if_reads = ifmap_words * n_f_passes * row_folds
   weight_words = f["weight_words"]
-  weights_fit = weight_words * c["weight_bits"] <= 0.25 * gbuf_bits
+  weights_fit = weight_words * right_bits <= 0.25 * gbuf_bits
   dram_w = weight_words * xp.where(weights_fit, 1.0, col_folds)
   gbuf_w_reads = weight_words * col_folds
   of_words = f["of_words"]
@@ -399,13 +583,20 @@ def _simulate_layer_feats(c, f, clock_mhz, xp) -> LayerStatsBatch:
 
   # ---- bandwidth bound ---------------------------------------------------
   cycle_s = 1e-6 / clock_mhz
-  dram_bits = (dram_if * c["act_bits"] + dram_w * c["weight_bits"]
+  dram_bits = (dram_if * c["act_bits"] + dram_w * right_bits
                + dram_of * c["psum_bits"])
   dram_time_s = dram_bits / 8.0 / (c["bandwidth_gbps"] * 1e9)
   dram_cycles = dram_time_s / cycle_s
   dram_stall = xp.maximum(0.0, dram_cycles - 0.85 * compute_cycles)
   cycles = compute_cycles + dram_stall
 
+  if "groups" in f:  # groups run one after another
+    g = f["groups"]
+    cycles, compute_cycles, dram_stall = (
+        cycles * g, compute_cycles * g, dram_stall * g)
+    macs, spad_reads, spad_writes = macs * g, spad_reads * g, spad_writes * g
+    gbuf_reads, gbuf_writes = gbuf_reads * g, gbuf_writes * g
+    dram_reads, dram_writes = dram_reads * g, dram_writes * g
   return LayerStatsBatch(
       cycles=cycles, compute_cycles=compute_cycles,
       dram_stall_cycles=dram_stall, utilization=utilization, macs=macs,
@@ -434,16 +625,17 @@ def _layer_energy_feats(c, f, stats: LayerStatsBatch, clock_mhz,
   """Hierarchical energy formulas over HW columns x layer features (pJ),
   broadcasting like :func:`_simulate_layer_feats`."""
   e = pe_lib.ENERGY_PJ
+  right_bits = _right_bits_feats(c, f, xp)
   mac_e = stats.macs * c["mac_energy_pj"]
   k = xp.maximum(f["K"], 1.0)
-  spad_read_bits = stats.macs * (c["act_bits"] + c["weight_bits"]
+  spad_read_bits = stats.macs * (c["act_bits"] + right_bits
                                  + c["psum_bits"] / k)
   spad_write_bits = stats.spad_writes * c["psum_bits"]
   spad_e = (spad_read_bits + spad_write_bits) * e["spad_access_per_bit"]
   gbuf_bits = (stats.gbuf_reads + stats.gbuf_writes) * (
-      (c["act_bits"] + c["weight_bits"] + c["psum_bits"]) / 3.0)
+      (c["act_bits"] + right_bits + c["psum_bits"]) / 3.0)
   gbuf_e = gbuf_bits * e["gbuf_access_per_bit"]
-  dram_bits = (stats.dram_reads * (c["act_bits"] + c["weight_bits"]) / 2.0
+  dram_bits = (stats.dram_reads * (c["act_bits"] + right_bits) / 2.0
                + stats.dram_writes * c["psum_bits"])
   dram_e = dram_bits * e["dram_access_per_bit"]
   time_s = stats.cycles / (clock_mhz * 1e6)
@@ -472,10 +664,78 @@ def simulate_network_batch(table, layers: Sequence[ConvLayer],
   util_weighted = 0.0
   for layer in layers:
     st = simulate_layer_batch(c, layer, clock_mhz, xp=xp)
-    total_cycles = total_cycles + st.cycles
-    total_energy_pj = total_energy_pj + layer_energy_pj_batch(
-        c, layer, st, clock_mhz, leakage_mw, xp=xp)
-    util_weighted = util_weighted + st.utilization * st.cycles
+    e_pj = layer_energy_pj_batch(c, layer, st, clock_mhz, leakage_mw, xp=xp)
+    n = layer_count(layer)
+    if n == 1:  # no multiply: conv programs trace as they always have
+      total_cycles = total_cycles + st.cycles
+      total_energy_pj = total_energy_pj + e_pj
+      util_weighted = util_weighted + st.utilization * st.cycles
+    else:
+      total_cycles = total_cycles + n * st.cycles
+      total_energy_pj = total_energy_pj + n * e_pj
+      util_weighted = util_weighted + n * (st.utilization * st.cycles)
+  latency_s = total_cycles / (clock_mhz * 1e6)
+  utilization = util_weighted / xp.maximum(total_cycles, 1e-12)
+  return latency_s, total_energy_pj * 1e-9, utilization  # pJ -> mJ
+
+
+# ---------------------------------------------------------------------------
+# layer tables: merged entries as arrays, one (n_entries, n_hw) pass
+# ---------------------------------------------------------------------------
+
+# the feature columns of a layer table, the keys of _layer_feats for a
+# GemmLayer (a conv row takes E_row = E, groups = 1, cache = 0)
+TABLE_FEATURES = ("E", "E_row", "K", "C", "F", "macs", "ifmap_words",
+                  "weight_words", "of_words", "groups", "cache")
+
+
+def layer_table(layers: Sequence) -> Tuple[Dict[str, np.ndarray],
+                                          np.ndarray]:
+  """``(cols, counts)`` of a layer list, entry by entry in its order:
+  each feature of :data:`TABLE_FEATURES` as an ``(n_entries, 1)`` float64
+  column, and each entry's count as an ``(n_entries,)`` float64 array.
+  Identical layers should already be merged into one entry
+  (:func:`repro.core.workloads.from_config` does)."""
+  rows = []
+  for layer in layers:
+    f = _layer_feats(layer)
+    f.setdefault("E_row", f["E"])
+    f.setdefault("groups", 1.0)
+    f.setdefault("cache", 0.0)
+    rows.append([f[k] for k in TABLE_FEATURES])
+  feats = np.asarray(rows, np.float64).reshape(len(rows), len(TABLE_FEATURES))
+  cols = {k: feats[:, i:i + 1].copy() for i, k in enumerate(TABLE_FEATURES)}
+  counts = np.asarray([layer_count(l) for l in layers], np.float64)
+  return cols, counts
+
+
+def simulate_network_table(table, cols, counts, clock_mhz, leakage_mw,
+                           xp=np):
+  """:func:`simulate_network_batch` over a layer table: the dataflow and
+  energy formulas run once over the ``(n_entries, n_hw)`` grid, then the
+  entries add up in their order, each weighted by its count — the same
+  additions, in the same order, as the per-layer loop, so the numpy path
+  is bit-identical to it (a count of 1 multiplies exactly).  ``cols`` and
+  ``counts`` come from :func:`layer_table`, and may be traced arrays: a
+  program over them serves every table with as many entries."""
+  c = _cols_of(table)
+  st = _simulate_layer_feats(c, cols, clock_mhz, xp)
+  e_pj = _layer_energy_feats(c, cols, st, clock_mhz, leakage_mw, xp)
+  grids = (st.cycles, e_pj, st.utilization * st.cycles)
+
+  def add(i, totals):
+    n = counts[i]
+    return tuple(t + n * g[i] for t, g in zip(totals, grids))
+
+  totals = (0.0, 0.0, 0.0)
+  if xp is np:
+    for i in range(counts.shape[0]):
+      totals = add(i, totals)
+  else:  # a loop, not n copies of the step: the program stays small
+    import jax
+    zero = xp.zeros_like(grids[0][0])
+    totals = jax.lax.fori_loop(0, counts.shape[0], add, (zero,) * 3)
+  total_cycles, total_energy_pj, util_weighted = totals
   latency_s = total_cycles / (clock_mhz * 1e6)
   utilization = util_weighted / xp.maximum(total_cycles, 1e-12)
   return latency_s, total_energy_pj * 1e-9, utilization  # pJ -> mJ

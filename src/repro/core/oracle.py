@@ -31,7 +31,7 @@ import numpy as np
 
 from repro.core import pe as pe_lib
 from repro.core.dataflow import (AcceleratorConfig, ConvLayer, LayerStats,
-                                 simulate_network)
+                                 layer_count, simulate_network)
 from repro.core.table import scratch_buf as _scratch_buf
 
 # Characterization-model version: bump whenever oracle outputs change for
@@ -255,7 +255,9 @@ class Characterization:
 
 def characterize(cfg: AcceleratorConfig,
                  layers: Sequence[ConvLayer]) -> Characterization:
-  """Synthesize + simulate one (hardware, network) pair.
+  """Synthesize + simulate one (hardware, network) pair.  Per-layer
+  cycles and energy are one instance's; totals count each entry
+  ``count`` times (:class:`repro.core.dataflow.GemmLayer`).
 
   This is the slow path (a Python-level per-layer dataflow walk standing in
   for hours of synthesis + RTL simulation); QUIDAM's polynomial models are
@@ -271,9 +273,10 @@ def characterize(cfg: AcceleratorConfig,
   # left to right, as the vectorized oracle adds: builtin sum() of floats
   # compensates its rounding (Python >= 3.12) and lands an ulp away
   util_weighted = total_cycles = 0.0
-  for s in stats:
-    util_weighted += s.utilization * s.cycles
-    total_cycles += s.cycles
+  for l, s in zip(layers, stats):
+    n = layer_count(l)
+    util_weighted += n * (s.utilization * s.cycles)
+    total_cycles += n * s.cycles
   util = util_weighted / max(total_cycles, 1e-12)
   return Characterization(
       clock_mhz=clk, area_mm2=area_mm2(cfg), power_mw=power_mw(cfg),
@@ -618,6 +621,24 @@ def characterize_batch(table, layers: Sequence[ConvLayer], xp=np,
   clock, power, area, leak = hw_batch_targets(c, xp)
   latency_s, energy_mj, utilization = simulate_network_batch(
       c, layers, clock, leak, xp=xp)
+  return BatchCharacterization(
+      clock_mhz=clock, area_mm2=area, power_mw=power,
+      latency_s=latency_s, energy_mj=energy_mj, utilization=utilization)
+
+
+def characterize_table(table, cols, counts, xp=np,
+                       inputs: Optional[Dict] = None
+                       ) -> BatchCharacterization:
+  """:func:`characterize_batch` over a layer table
+  (:func:`repro.core.dataflow.layer_table`): bit-identical on the numpy
+  path, with the layer features as arrays, so that the ``jax.jit``
+  device path compiles one program per number of entries (see
+  :func:`repro.core.dataflow.simulate_network_table`)."""
+  from repro.core.dataflow import simulate_network_table
+  c = inputs if inputs is not None else batch_inputs(table)
+  clock, power, area, leak = hw_batch_targets(c, xp)
+  latency_s, energy_mj, utilization = simulate_network_table(
+      c, cols, counts, clock, leak, xp=xp)
   return BatchCharacterization(
       clock_mhz=clock, area_mm2=area, power_mw=power,
       latency_s=latency_s, energy_mj=energy_mj, utilization=utilization)

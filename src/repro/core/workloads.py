@@ -2,17 +2,21 @@
 
 Provides the paper's evaluation networks — VGG-16, ResNet-20/34/50/56 on
 CIFAR (32x32) and ImageNet (224x224) — as row-stationary workload layer
-lists, plus a *bridge* that lowers any transformer architecture from the
-assigned zoo (``repro.configs``) into the same workload IR (matmuls as
-1x1 convolutions), so the paper's PPA models co-explore LM architectures
-as well (beyond-paper extension, see README.md "LM workloads bridge").
+lists; :func:`from_config`, which lowers a ``repro.configs`` model in one
+deployment regime into merged GEMM entries (:class:`GemmLayer`, exact
+shapes, counts); and the older *bridge* :func:`lm_block_workload`, which
+treats a transformer block's matmuls as 1x1 convolutions over a square
+map (beyond-paper extension, see README.md "LM workloads bridge").
 """
 from __future__ import annotations
 
+import dataclasses
 import math
-from typing import Dict, List, Sequence
+from typing import Dict, List, Optional, Sequence
 
-from repro.core.dataflow import ConvLayer
+import numpy as np
+
+from repro.core.dataflow import ConvLayer, GemmLayer
 
 
 # ---------------------------------------------------------------------------
@@ -145,6 +149,150 @@ def lm_block_workload(name: str, tokens: int, d_model: int, n_heads: int,
     layers.append(matmul_layer(f"{name}.ffn{i}",
                                tokens * n_experts_active, d_in, d_out))
   return layers
+
+
+# ---------------------------------------------------------------------------
+# model configs -> merged GEMM entries
+# ---------------------------------------------------------------------------
+
+def expert_token_counts(cfg, tokens: int) -> List[np.ndarray]:
+  """Tokens each routed expert receives in one step, one int64 array of
+  ``n_experts`` per MoE layer, by the config's synthetic routing.
+
+  For MoE layer ``i`` (its index in the model): ``rng =
+  numpy.random.RandomState(derive_seed("moe-routing", router_seed, i))``;
+  ``perm = rng.permutation(n_experts)``; expert ``perm[r]`` has
+  popularity ``(r + 1) ** -router_skew``, normalized to sum to 1; then
+  each of the ``tokens`` tokens in turn draws ``rng.choice(n_experts,
+  n_experts_active, replace=False, p=popularity)``.
+  """
+  from repro.core.seeding import derive_seed
+  e, k = cfg.n_experts, cfg.n_experts_active
+  weights = np.arange(1, e + 1, dtype=np.float64) ** -float(cfg.router_skew)
+  out = []
+  for i in range(cfg.first_dense_layers, cfg.n_layers):
+    rng = np.random.RandomState(derive_seed("moe-routing", cfg.router_seed,
+                                            i))
+    perm = rng.permutation(e)
+    p = np.empty(e)
+    p[perm] = weights / weights.sum()
+    t = np.zeros(e, np.int64)
+    for _ in range(tokens):
+      t[rng.choice(e, k, replace=False, p=p)] += 1
+    out.append(t)
+  return out
+
+
+def _regime(shape):
+  """A ``repro.configs.shapes`` regime, by name or as its ShapeSpec."""
+  if isinstance(shape, str):
+    from repro.configs.shapes import SHAPES  # deferred: imports jax
+    return SHAPES[shape]
+  return shape
+
+
+def from_config(cfg, shape, routing: Optional[Sequence] = None
+                ) -> List[GemmLayer]:
+  """One step of ``cfg`` in the regime ``shape`` as merged GEMM entries,
+  in order of first appearance: identical layers (same M, K, N, groups
+  and operand) are one entry whose ``count`` says how many.
+
+  Lowered: ``decode`` (``global_batch`` sequences, one new token each,
+  against ``seq_len`` cached positions) of a model with multi-head latent
+  attention (``kv_lora_rank``, no query compression) and SwiGLU MLPs,
+  dense or DeepSeekMoE.  B tokens, L cached positions, H heads, per layer:
+
+    q_proj     B x d -> H (dn + dr)
+    q_absorb   groups H: B x dn -> r            (W_UK absorbed)
+    kv_a_proj  B x d -> r + dr                  (written to the cache)
+    scores     groups B: H x (r + dr) -> L      cache operand
+    av         groups B: H x L -> r             cache operand
+    v_absorb   groups H: B x r -> dv            (W_UV absorbed)
+    o_proj     B x H dv -> d
+
+  then a dense MLP (``first_dense_layers`` leading layers) or an MoE
+  layer: the router B x d -> n_experts, the shared experts as one MLP of
+  width ``d_ff_shared``, and for each routed expert with t > 0 tokens
+  gate and up t x d -> d_ff_expert and down t x d_ff_expert -> d (an
+  expert with no tokens reads no weights).  Last, ``lm_head`` B x d ->
+  vocab.  ``routing`` gives the routed tokens per expert, one sequence
+  per MoE layer; None draws them by :func:`expert_token_counts`.
+
+  Left out: softmax and the norms (elementwise work outside the MAC
+  array; the scores still go to DRAM and come back as the av product's
+  input), the rope product, and the embedding lookup.  Any other regime
+  or mechanism raises ``NotImplementedError``.
+  """
+  from repro.explore import spans  # deferred: explore imports this module
+  with spans.span("lower"):
+    spec = _regime(shape)
+    if spec.mode != "decode":
+      raise NotImplementedError(
+          f"from_config lowers decode only, not {spec.mode!r} ({spec.name})")
+    why = []
+    if cfg.kv_lora_rank <= 0:
+      why.append("attention other than multi-head latent attention")
+    if cfg.q_lora_rank > 0:
+      why.append("compressed queries (q_lora_rank)")
+    if cfg.sliding_window:
+      why.append("windowed attention")
+    if cfg.family not in ("dense", "moe"):
+      why.append(f"the {cfg.family!r} family")
+    if cfg.mlp_variant != "swiglu":
+      why.append(f"the {cfg.mlp_variant!r} MLP")
+    if cfg.n_experts and cfg.moe_period != 1:
+      why.append("MoE layers at a period other than 1")
+    if why:
+      raise NotImplementedError(f"from_config cannot lower {cfg.name}: "
+                                + "; ".join(why))
+    b, ctx = spec.global_batch, spec.seq_len
+    d, h = cfg.d_model, cfg.n_heads
+    dn, dr, dv, r = (cfg.head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+                     cfg.kv_lora_rank)
+    n_moe = cfg.n_layers - cfg.first_dense_layers if cfg.n_experts else 0
+    if n_moe:
+      counts = expert_token_counts(cfg, b) if routing is None \
+          else [np.asarray(t, np.int64) for t in routing]
+      if len(counts) != n_moe or any(len(t) != cfg.n_experts
+                                     for t in counts):
+        raise ValueError(f"routing needs {n_moe} layers of "
+                         f"{cfg.n_experts} expert token counts")
+    entries: List[GemmLayer] = []
+    where: Dict[tuple, int] = {}
+
+    def add(name, m, k, n, groups=1, operand="weight", count=1):
+      layer = GemmLayer(name, int(m), int(k), int(n), int(groups), operand,
+                        count)
+      i = where.setdefault(layer.shape, len(entries))
+      if i == len(entries):
+        entries.append(layer)
+      else:
+        entries[i] = dataclasses.replace(entries[i],
+                                         count=entries[i].count + count)
+
+    def mlp(prefix, tokens, width):
+      add(f"{prefix}.gate_up", tokens, d, width, count=2)
+      add(f"{prefix}.down", tokens, width, d)
+
+    for i in range(cfg.n_layers):
+      add("attn.q_proj", b, d, h * (dn + dr))
+      add("attn.q_absorb", b, dn, r, groups=h)
+      add("attn.kv_a_proj", b, d, r + dr)
+      add("attn.scores", h, r + dr, ctx, groups=b, operand="cache")
+      add("attn.av", h, ctx, r, groups=b, operand="cache")
+      add("attn.v_absorb", b, r, dv, groups=h)
+      add("attn.o_proj", b, h * dv, d)
+      if i < cfg.n_layers - n_moe:
+        mlp("mlp", b, cfg.d_ff)
+        continue
+      add("moe.router", b, d, cfg.n_experts)
+      if cfg.n_shared_experts:
+        mlp("moe.shared", b, cfg.d_ff_shared)
+      for t in counts[i - (cfg.n_layers - n_moe)]:
+        if t > 0:
+          mlp(f"moe.expert[t={int(t)}]", t, cfg.d_ff_expert)
+    add("lm_head", b, d, cfg.vocab_size)
+    return entries
 
 
 # ---------------------------------------------------------------------------

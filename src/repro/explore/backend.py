@@ -35,7 +35,8 @@ import numpy as np
 
 from repro.core import oracle
 from repro.core import ppa as ppa_lib
-from repro.core.dataflow import AcceleratorConfig, ConvLayer, LayerStack
+from repro.core.dataflow import (AcceleratorConfig, ConvLayer, GemmLayer,
+                                 LayerStack, layer_table)
 from repro.core.pe import PAPER_PE_TYPES
 from repro.core.table import ConfigTable
 from repro.explore import spans
@@ -95,6 +96,14 @@ def gbuf_overheads_table(table: ConfigTable, xp=np
   inputs = oracle.batch_inputs(table)
   return (oracle.gbuf_power_mw_batch(table, xp=xp, inputs=inputs),
           oracle.gbuf_area_mm2_batch(table, xp=xp, inputs=inputs))
+
+
+@functools.lru_cache(maxsize=16)
+def _table_of(layers: Tuple) -> Tuple:
+  """A GEMM network's layer table, once per layer tuple (the device path
+  takes it as program arguments, see :func:`repro.explore.device
+  .make_table_fn`)."""
+  return layer_table(layers)
 
 
 # ---------------------------------------------------------------------------
@@ -327,11 +336,13 @@ class VectorOracleBackend:
   # -- optional device path -------------------------------------------------
   # Joint programs take the sweep content (inputs bundle, dedup'd stack
   # arrays) as arguments — one LRU entry per (path kind, plan, precision),
-  # jax handles shape specialization.  Plain-sweep programs still bake
-  # the layer tuple into the trace (layer features are scalars there, and
-  # one sweep evaluates one network), so their entries are per layer
-  # tuple and sessions sweeping many networks recompile under LRU
-  # eviction.
+  # jax handles shape specialization.  So do plain sweeps of a network
+  # with GEMM layers: their layer table enters as arrays
+  # (``eval_table`` / ``fused_eval_table``), one program per entry count.
+  # Plain sweeps of a conv network still bake the layer tuple into the
+  # trace (layer features are scalars there, and one sweep evaluates one
+  # network), so their entries are per layer tuple and sessions sweeping
+  # many networks recompile under LRU eviction.
 
   def _x64(self):
     """Precision context: trace/run with float64 for the exact path."""
@@ -407,18 +418,20 @@ class VectorOracleBackend:
 
   @staticmethod
   def _place(inputs, dev, commit: bool = False):
-    """Copy a chunk's input columns to the pinned device, so the jitted
-    program executes there; with ``commit`` and no pin, to the default
-    device, so that the copy is a stage of its own rather than part of
-    the call.  Unpinned, uncommitted inputs stay on the host (the
-    row-sharded program splits them itself).  Must run inside the
-    ``_x64`` context — ``device_put`` canonicalizes dtypes, and float64
-    inputs would be silently downcast outside it."""
+    """Copy a chunk's input columns (any pytree of arrays: the columns
+    and a layer table) to the pinned device, so the jitted program
+    executes there; with ``commit`` and no pin, to the default device,
+    so that the copy is a stage of its own rather than part of the call.
+    Unpinned, uncommitted inputs stay on the host (the row-sharded
+    program splits them itself).  Must run inside the ``_x64`` context —
+    ``device_put`` canonicalizes dtypes, and float64 inputs would be
+    silently downcast outside it."""
     if dev is None and not commit:
       return inputs
     import jax
     with spans.span("place"):
-      spans.count_bytes(sum(v.nbytes for v in inputs.values()))
+      spans.count_bytes(sum(v.nbytes
+                            for v in jax.tree_util.tree_leaves(inputs)))
       return jax.device_put(inputs, dev)
 
   def _eval_fn(self, layers: Tuple[ConvLayer, ...], plan=None,
@@ -434,6 +447,29 @@ class VectorOracleBackend:
 
     return self._cached_fn(("eval", layers, plan, self.precision, pinned),
                            build)
+
+  def _table_fn(self, plan=None, pinned: bool = False):
+    from repro.explore import device as device_lib
+    pinned = bool(pinned) and plan is None  # fused programs never shard
+
+    def build():
+      fn = device_lib.make_table_fn(plan)
+      if plan is None and not pinned:
+        fn = self._shard_rows(fn, joint=False)
+      return self._jit(fn)
+
+    return self._cached_fn(("eval_table", plan, self.precision, pinned),
+                           build)
+
+  def _program(self, layers: Tuple[ConvLayer, ...], plan=None,
+               pinned: bool = False):
+    """The plain-sweep program for ``layers`` and the arguments it takes
+    after the input columns: a network with GEMM layers runs the table
+    program over its layer table, a conv network the program its layers
+    are baked into."""
+    if any(isinstance(l, GemmLayer) for l in layers):
+      return self._table_fn(plan, pinned), _table_of(layers)
+    return self._eval_fn(layers, plan, pinned), ()
 
   def _joint_fn(self, plan=None, pinned: bool = False):
     from repro.explore import device as device_lib
@@ -452,7 +488,8 @@ class VectorOracleBackend:
     import jax
     inputs = oracle.batch_inputs(chunk)  # variations need host uint64
     with self._x64():
-      l, p, a = self._eval_fn(layers)(inputs)
+      fn, args = self._program(layers)
+      l, p, a = fn(inputs, *args)
     return (np.asarray(jax.device_get(l), np.float64),
             np.asarray(jax.device_get(p), np.float64),
             np.asarray(jax.device_get(a), np.float64))
@@ -485,9 +522,10 @@ class VectorOracleBackend:
         inputs = oracle.batch_inputs(table)
       dev = self._pinned()
       with self._x64():
-        inputs = self._place(inputs, dev)
+        fn, args = self._program(layers, pinned=dev is not None)
+        inputs, args = self._place((inputs, args), dev)
         with spans.span("launch"):
-          out = self._eval_fn(layers, pinned=dev is not None)(inputs)
+          out = fn(inputs, *args)
 
     def finalize():
       l, p, a = (np.asarray(jax.device_get(o), np.float64) for o in out)
@@ -535,9 +573,11 @@ class VectorOracleBackend:
       with spans.span("batch_inputs"):
         inputs = oracle.batch_inputs(table)
       with self._x64():
-        inputs = self._place(inputs, self._pinned(), commit=True)
+        fn, args = self._program(layers, plan)
+        inputs, args = self._place((inputs, args), self._pinned(),
+                                   commit=True)
         with spans.span("launch"):
-          outputs = self._eval_fn(layers, plan)(inputs)
+          outputs = fn(inputs, *args)
     return device_lib.PendingFused(outputs, plan, table, idx, network)
 
   def fused_co_eval_pending(self, hw: ConfigTable, stack: LayerStack,

@@ -464,6 +464,30 @@ def make_eval_fn(layers: Tuple[ConvLayer, ...],
   return named(run, "eval" if plan is None else "fused_eval")
 
 
+def make_table_fn(plan: Optional[DevicePlan]) -> Callable:
+  """Plain-sweep program over a layer table
+  (:func:`repro.core.dataflow.layer_table`): (inputs, cols, counts) ->
+  (lat, pwr, area)[, reductions].
+
+  The entries' features and counts enter as arrays, so one jitted
+  callable serves every network with as many entries (jax re-traces per
+  entry count, not per network, layer shape or routing draw)."""
+  import jax
+  import jax.numpy as jnp
+
+  def run(inputs, layer_cols, counts):
+    ch = oracle.characterize_table(None, layer_cols, counts, xp=jnp,
+                                   inputs=inputs)
+    full = (ch.latency_s, ch.power_mw, ch.area_mm2)
+    if plan is None:
+      return full
+    cols = _derive_columns(ch.latency_s[None, :], ch.power_mw[None, :],
+                           ch.area_mm2[None, :], jnp)
+    return full, _reduce_outputs(cols, plan, grouped=False, jnp=jnp, jax=jax)
+
+  return named(run, "eval_table" if plan is None else "fused_eval_table")
+
+
 def make_joint_fn(plan: Optional[DevicePlan]) -> Callable:
   """Joint-sweep program over the distinct-layer factorization:
   (inputs, unique_cols, slot_ids, valid, accs) ->

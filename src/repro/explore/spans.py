@@ -33,10 +33,11 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-# every stage a chunk can pass through, outermost first
+# every stage a chunk can pass through, outermost first, and the lowering
+# of a model config into layers (repro.core.workloads.from_config)
 SPANS = ("stream", "plan", "sample", "dispatch", "batch_inputs", "place",
          "launch", "resolve", "wait", "slice", "fetch", "fold_chunk",
-         "speculate", "sdc_check")
+         "speculate", "sdc_check", "lower")
 
 # the clock spans are timed on (tests inject their own)
 clock = time.perf_counter

@@ -59,8 +59,8 @@ from repro.explore.streaming import (Reducer, StreamResult,
                                      default_co_reducers,
                                      default_explore_reducers,
                                      default_workers, explore_tasks,
-                                     run_stream, stream_co_explore,
-                                     stream_explore)
+                                     layer_meta, run_stream,
+                                     stream_co_explore, stream_explore)
 
 STORE_VERSION = 1
 
@@ -414,6 +414,7 @@ def cached_stream_explore(backend, space: DesignSpace, layers,
                        checkpoint_every=checkpoint_every, pool=pool)
       res.meta["delta_sweep"] = 1.0
       res.meta["n_delta_rows"] = float(res.n_rows)
+      res.meta.update(layer_meta(layers))
       res.n_rows += int(base_state.get("n_rows", 0))
       store.put_final(rkey, _snapshot_state(reducers, res),
                       _explore_manifest(space, network, method, rfp,

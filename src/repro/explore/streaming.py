@@ -936,12 +936,23 @@ def stream_explore(backend, space: DesignSpace, layers, network: str = "net",
       key = explore_sweep_key(space, reducers, n_per_type=n_per_type,
                               seed=seed, method=method,
                               chunk_size=chunk_size, network=network)
-    return run_stream(tasks, reducers,
-                      workers=default_workers(backend) if workers is None
-                      else workers,
-                      policy=policy, resume_from=resume_from,
-                      journal_key=key, checkpoint_every=checkpoint_every,
-                      pool=pool)
+    out = run_stream(tasks, reducers,
+                     workers=default_workers(backend) if workers is None
+                     else workers,
+                     policy=policy, resume_from=resume_from,
+                     journal_key=key, checkpoint_every=checkpoint_every,
+                     pool=pool)
+  out.meta.update(layer_meta(layers))
+  return out
+
+
+def layer_meta(layers) -> Dict[str, float]:
+  """What a plain sweep evaluated per point: ``n_layer_shapes``, the
+  layer entries (each a distinct shape once identical layers merge),
+  and ``n_layer_slots``, the layers they stand for (the sum of counts)."""
+  from repro.core.dataflow import layer_count
+  return {"n_layer_shapes": float(len(layers)),
+          "n_layer_slots": float(sum(layer_count(l) for l in layers))}
 
 
 def stream_co_explore(backend, space: DesignSpace, arch_accs,
