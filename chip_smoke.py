@@ -375,7 +375,6 @@ class ShardedPhase:
     return [lambda: self.backend.co_evaluate_table(self.hw, self.stack)]
 
   def run(self, exact: bool) -> bool:
-    from repro.core import oracle
     from repro.explore import VectorOracleBackend
     from repro.explore.fleet import visible_devices
     dev, wall, cs, nc = timed(
@@ -390,9 +389,10 @@ class ShardedPhase:
     # the rows never left device 0
     unique_cols, slot_ids = self.stack.dedup_slots()
     with self.backend._x64():
-      out = self.backend._joint_fn()(oracle.batch_inputs(self.hw),
-                                     unique_cols, slot_ids, self.stack.valid,
-                                     np.zeros(0))
+      inputs = self.backend._inputs(self.hw,
+                                    len(self.hw) * self.stack.n_archs)
+      out = self.backend._joint_fn()(inputs, unique_cols, slot_ids,
+                                     self.stack.valid, np.zeros(0))
     n_out_devices = min(len(o.sharding.device_set) for o in out)
     ok = (identical if exact else max_rel <= VALUE_REL_BOUND) \
         and n_out_devices == len(visible_devices())

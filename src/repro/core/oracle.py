@@ -32,6 +32,7 @@ import numpy as np
 from repro.core import pe as pe_lib
 from repro.core.dataflow import (AcceleratorConfig, ConvLayer, LayerStats,
                                  layer_count, simulate_network)
+from repro.core.table import INT_COLUMNS
 from repro.core.table import scratch_buf as _scratch_buf
 
 # Characterization-model version: bump whenever oracle outputs change for
@@ -53,9 +54,10 @@ ARRAY_CTRL_GATES = 12_000   # top-level controller, address generators
 # Layout variation hashes the design point's KEY COLUMNS (not a formatted
 # key string): salt and PE-type names are folded in as one-time SHA-256
 # constants, then each knob column is chained through a splitmix64-style
-# finalizer.  The same mixer runs per-row on Python ints (scalar path) and
-# on uint64 numpy columns (:func:`_variation_batch`), so the vectorized
-# million-point path is bit-identical to the scalar oracle by construction.
+# finalizer.  The same mixer runs per-row on Python ints (scalar path), on
+# uint64 numpy columns (:func:`_variation_batch`) and in x64 device
+# programs (:func:`variation_columns`), so the vectorized million-point
+# paths are bit-identical to the scalar oracle by construction.
 _MASK64 = (1 << 64) - 1
 
 
@@ -301,63 +303,112 @@ def characterize_layer_latency(cfg: AcceleratorConfig, layer: ConvLayer
 # :class:`repro.core.table.ConfigTable` column-at-a-time.  The formulas are
 # written against an array module ``xp`` (numpy by default; jax.numpy for
 # the optional device path) and mirror the scalar expressions op for op, so
-# the numpy path is bit-identical to looping the scalar oracle.  The
-# variation term is precomputed with numpy uint64 arithmetic either way
-# (jax traces treat it as an input), because the mixer needs uint64.
+# the numpy path is bit-identical to looping the scalar oracle.  So is the
+# variation chain: the host runs it on uint64 numpy columns, and an x64
+# device program runs the same ops on the keys its bundle carries
+# (:func:`variation_columns`).
+
+# the three variation multipliers, each the column ``var_<salt>``
+VARIATIONS = (("clk", 0.004), ("area", 0.005), ("pwr", 0.005))
+_U64 = np.uint64
 
 
-def _mix64_batch(z: np.ndarray, out: Optional[np.ndarray] = None
-                 ) -> np.ndarray:
-  """splitmix64 finalizer across a uint64 column (wraps mod 2^64)."""
-  z = np.multiply(z ^ (z >> np.uint64(30)), np.uint64(0xBF58476D1CE4E5B9),
-                  out=out)
-  z = np.multiply(z ^ (z >> np.uint64(27)), np.uint64(0x94D049BB133111EB),
-                  out=out)
-  return np.bitwise_xor(z, z >> np.uint64(31), out=out)
+def _mix64_batch(z, out: Optional[np.ndarray] = None, xp=np):
+  """splitmix64 finalizer across a uint64 column (wraps mod 2^64): numpy
+  may mix in place into ``out``; a jax ``xp`` traces the same ops."""
+  kw = {} if out is None else {"out": out}
+  z = xp.multiply(z ^ (z >> _U64(30)), _U64(0xBF58476D1CE4E5B9), **kw)
+  z = xp.multiply(z ^ (z >> _U64(27)), _U64(0x94D049BB133111EB), **kw)
+  return xp.bitwise_xor(z, z >> _U64(31), **kw)
+
+
+def _variation_chain(keys: Sequence, salt: str, pct: float, xp=np,
+                     h: Optional[np.ndarray] = None,
+                     u: Optional[np.ndarray] = None):
+  """:func:`_variation` over uint64 key columns in the order of
+  :func:`_variation_key_ints`: one multiplier per row.  numpy works in
+  place in ``h`` (uint64) and ``u`` (float64) when they are given; a
+  jax ``xp`` makes new arrays."""
+  hk = {} if h is None else {"out": h}
+  uk = {} if u is None else {"out": u}
+  h = xp.bitwise_xor(_U64(_name_const(salt)), keys[0], **hk)
+  h = _mix64_batch(h, xp=xp, **hk)
+  for v in keys[1:]:
+    h = _mix64_batch(xp.bitwise_xor(h, v, **hk), xp=xp, **hk)
+  # same IEEE op sequence as the scalar form: /2^64, *2, -1, *pct, +1
+  u = xp.true_divide(h, 2.0**64, **uk)
+  u = xp.multiply(u, 2.0, **uk)
+  u = xp.subtract(u, 1.0, **uk)
+  u = xp.multiply(u, pct, **uk)
+  return xp.add(u, 1.0, **uk)
+
+
+def _type_consts(table) -> np.ndarray:
+  """Each row's PE-type name constant (uint64); one scalar, which
+  broadcasts, for a one-type table."""
+  vocab = np.asarray([_name_const(t) for t in table.pe_type_names],
+                     np.uint64)
+  return vocab[0] if len(vocab) == 1 else vocab[table.pe_code]
+
+
+def _bandwidth_bits(table) -> np.ndarray:
+  # a ConfigTable's bandwidth column is float64: a view, not a copy
+  return table.bandwidth_gbps.view(np.uint64)
 
 
 def _variation_batch(table, salt: str, pct: float,
                      scratch: Optional[Dict] = None) -> np.ndarray:
   """Vectorized :func:`_variation`: one multiplier per table row."""
   n = len(table)
-  type64 = np.asarray([_name_const(t) for t in table.pe_type_names],
-                      np.uint64)[table.pe_code]
+  keys = (_type_consts(table),
+          *(getattr(table, k).astype(np.uint64) for k in INT_COLUMNS),
+          _bandwidth_bits(table))
   h = _scratch_buf(scratch, f"var64_{salt}", n, np.uint64)
-  if h is None:
-    h = np.empty(n, np.uint64)
-  h[...] = _name_const(salt)
-  cols = (type64,
-          table.pe_rows.astype(np.uint64), table.pe_cols.astype(np.uint64),
-          table.sp_if.astype(np.uint64), table.sp_fw.astype(np.uint64),
-          table.sp_ps.astype(np.uint64), table.gbuf_kb.astype(np.uint64),
-          table.bandwidth_gbps.astype(np.float64).view(np.uint64))
-  for v in cols:
-    np.bitwise_xor(h, v, out=h)
-    _mix64_batch(h, out=h)
   u = _scratch_buf(scratch, f"var_{salt}", n, np.float64)
-  if u is None:
-    u = np.empty(n, np.float64)
-  # same IEEE op sequence as the expression form: /2^64, *2, -1, *pct, +1
-  np.true_divide(h, 2.0**64, out=u)
-  np.multiply(u, 2.0, out=u)
-  np.subtract(u, 1.0, out=u)
-  np.multiply(u, pct, out=u)
-  np.add(u, 1.0, out=u)
-  return u
+  return _variation_chain(keys, salt, pct,
+                          h=np.empty(n, np.uint64) if h is None else h,
+                          u=np.empty(n, np.float64) if u is None else u)
 
 
-def batch_inputs(table, scratch: Optional[Dict] = None
-                 ) -> Dict[str, np.ndarray]:
+def variation_keys(table) -> np.ndarray:
+  """(n, 2) uint64: each row's PE-type constant and the bit pattern of
+  its ``bandwidth_gbps``, the keys of the variation chain that a device
+  cannot take from the float64 bundle (the TPU compiles no f64 -> u64
+  bitcast; the integer knobs convert there)."""
+  keys = np.empty((len(table), 2), np.uint64)
+  keys[:, 0] = _type_consts(table)
+  keys[:, 1] = _bandwidth_bits(table)
+  return keys
+
+
+def variation_columns(c, xp) -> Dict:
+  """``var_clk``, ``var_area`` and ``var_pwr`` from a bundle's
+  ``var_keys`` and its float64 knob columns: the chain of
+  :func:`_variation_batch`, run by the program that holds the bundle."""
+  keys = c["var_keys"]
+  chain = (keys[:, 0], *(c[k].astype(xp.uint64) for k in INT_COLUMNS),
+           keys[:, 1])
+  return {"var_" + salt: _variation_chain(chain, salt, pct, xp)
+          for salt, pct in VARIATIONS}
+
+
+def batch_inputs(table, scratch: Optional[Dict] = None,
+                 device_variations: bool = False) -> Dict[str, np.ndarray]:
   """The array bundle all batch formulas consume: numeric columns +
-  per-row PE constants + the three precomputed variation columns + the
+  per-row PE constants + the three variation columns + the
   transcendental terms (log2 / pow) of the area/clock formulas.
 
-  The transcendentals are precomputed with host numpy for the same reason
-  the variation columns are: they are pure functions of the config
-  columns, and libm (numpy) and XLA disagree by 1 ulp on ``log2``/``pow``
-  — precomputing them makes the ``jax.jit`` x64 device path bit-identical
-  to the numpy path by construction (basic arithmetic, ``sqrt``, ``ceil``
-  and floor-division are IEEE-exact in both).
+  With ``device_variations`` the three variation columns give way to
+  ``var_keys`` (:func:`variation_keys`), from which an x64 device
+  program derives them (:func:`variation_columns`), bit for bit the
+  host's on XLA:CPU under the exact-codegen flags.
+
+  The transcendentals are precomputed with host numpy: they are pure
+  functions of the config columns, and libm (numpy) and XLA disagree by
+  1 ulp on ``log2``/``pow`` — precomputing them makes the ``jax.jit``
+  x64 device path bit-identical to the numpy path by construction
+  (basic arithmetic, ``sqrt``, ``ceil`` and floor-division are
+  IEEE-exact in both).
 
   ``scratch`` (a plain dict owned by the caller, one per worker thread)
   lets repeated chunked calls reuse the feature temporaries instead of
@@ -366,9 +417,11 @@ def batch_inputs(table, scratch: Optional[Dict] = None
   call with the same scratch.
   """
   cols = table.numeric_columns(scratch=scratch)
-  cols["var_clk"] = _variation_batch(table, "clk", 0.004, scratch)
-  cols["var_area"] = _variation_batch(table, "area", 0.005, scratch)
-  cols["var_pwr"] = _variation_batch(table, "pwr", 0.005, scratch)
+  if device_variations:
+    cols["var_keys"] = variation_keys(table)
+  else:
+    for salt, pct in VARIATIONS:
+      cols["var_" + salt] = _variation_batch(table, salt, pct, scratch)
   n = len(table)
   l2pe = _scratch_buf(scratch, "log2_n_pe", n, np.float64)
   cols["log2_n_pe"] = np.log2(np.maximum(cols["n_pe"], 2.0), out=l2pe)

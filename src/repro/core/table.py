@@ -149,9 +149,12 @@ class ConfigTable:
       vocab = self._pe_const_vocab(field)
       b = scratch_buf(scratch, field, n, np.float64)
       if b is None:
-        cols[field] = vocab[self.pe_code]
+        b = np.empty(n, np.float64)
+      if len(vocab) == 1:  # a one-type table (every sampled chunk): a fill
+        b.fill(vocab[0])
       else:
-        cols[field] = np.take(vocab, self.pe_code, out=b)
+        np.take(vocab, self.pe_code, out=b)
+      cols[field] = b
     return cols
 
   def hw_features(self) -> np.ndarray:

@@ -167,10 +167,12 @@ class VectorOracleBackend:
 
   ``jit=True`` runs the per-chunk formulas under ``jax.jit`` as a
   first-class exact backend: the default ``precision="x64"`` traces with
-  float64 enabled and host-precomputed transcendental columns (see
-  :func:`repro.core.oracle.batch_inputs`), so device results are
+  float64 enabled and host-precomputed transcendental columns, and
+  derives the variation columns on the device from their uint64 keys
+  (see :func:`repro.core.oracle.batch_inputs`), so device results are
   **bit-identical** to the numpy path; ``precision="float32"`` keeps the
-  old approximate fast mode.  Joint sweeps compile the distinct-layer
+  old approximate fast mode, with no 64-bit integers, so its variation
+  columns come from the host.  Joint sweeps compile the distinct-layer
   factorization with the stack as a traced input, so one executable
   serves every arch block of a streaming sweep.  When several devices
   are visible, chunk rows shard across them via ``shard_map``.
@@ -483,10 +485,21 @@ class VectorOracleBackend:
 
     return self._cached_fn(("joint", plan, self.precision, pinned), build)
 
+  def _inputs(self, table: ConfigTable, n_rows: int) -> Dict:
+    """A device program's input bundle for ``table``, whose chunk has
+    ``n_rows`` result rows.  An x64 program derives the variation
+    columns itself; float32 runs without 64-bit integers and is handed
+    the host's."""
+    on_device = self.precision == "x64"
+    inputs = oracle.batch_inputs(table, device_variations=on_device)
+    if on_device:
+      spans.count_var_rows(n_rows)
+    return inputs
+
   def _eval_chunk_jax(self, chunk: ConfigTable,
                       layers: Tuple[ConvLayer, ...]):
     import jax
-    inputs = oracle.batch_inputs(chunk)  # variations need host uint64
+    inputs = self._inputs(chunk, len(chunk))
     with self._x64():
       fn, args = self._program(layers)
       l, p, a = fn(inputs, *args)
@@ -497,7 +510,7 @@ class VectorOracleBackend:
   def _co_eval_chunk_jax(self, chunk: ConfigTable, stack: LayerStack,
                          dedup=None):
     import jax
-    inputs = oracle.batch_inputs(chunk)
+    inputs = self._inputs(chunk, len(chunk) * stack.n_archs)
     unique_cols, slot_ids = stack.dedup_slots() if dedup is None else dedup
     with self._x64():
       # accs is only consumed by fused plans; an empty array keeps the
@@ -519,7 +532,7 @@ class VectorOracleBackend:
     layers = tuple(layers)
     with spans.span("dispatch"):
       with spans.span("batch_inputs"):
-        inputs = oracle.batch_inputs(table)
+        inputs = self._inputs(table, len(table))
       dev = self._pinned()
       with self._x64():
         fn, args = self._program(layers, pinned=dev is not None)
@@ -543,7 +556,7 @@ class VectorOracleBackend:
     from repro.explore import device as device_lib
     with spans.span("dispatch"):
       with spans.span("batch_inputs"):
-        inputs = oracle.batch_inputs(hw)
+        inputs = self._inputs(hw, len(hw) * stack.n_archs)
       unique_cols, slot_ids = stack.dedup_slots() if dedup is None \
           else dedup
       dev = self._pinned()
@@ -571,7 +584,7 @@ class VectorOracleBackend:
     layers = tuple(layers)
     with spans.span("dispatch"):
       with spans.span("batch_inputs"):
-        inputs = oracle.batch_inputs(table)
+        inputs = self._inputs(table, len(table))
       with self._x64():
         fn, args = self._program(layers, plan)
         inputs, args = self._place((inputs, args), self._pinned(),
@@ -589,7 +602,7 @@ class VectorOracleBackend:
     accs = np.asarray(accs, np.float64)
     with spans.span("dispatch"):
       with spans.span("batch_inputs"):
-        inputs = oracle.batch_inputs(hw)
+        inputs = self._inputs(hw, len(hw) * stack.n_archs)
       unique_cols, slot_ids = stack.dedup_slots() if dedup is None \
           else dedup
       with self._x64():

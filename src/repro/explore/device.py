@@ -441,6 +441,16 @@ def named(fn: Callable, name: str) -> Callable:
   return fn
 
 
+def _with_variations(inputs: Dict, xp) -> Dict:
+  """The bundle the formulas read: one that carries ``var_keys`` (the
+  x64 path's, ``oracle.batch_inputs(..., device_variations=True)``)
+  gains its three variation columns here; one that holds them already
+  passes as it is."""
+  if "var_keys" not in inputs:
+    return inputs
+  return {**inputs, **oracle.variation_columns(inputs, xp)}
+
+
 def make_eval_fn(layers: Tuple[ConvLayer, ...],
                  plan: Optional[DevicePlan]) -> Callable:
   """Plain-sweep program: inputs bundle -> (lat, pwr, area)[, reductions].
@@ -453,7 +463,8 @@ def make_eval_fn(layers: Tuple[ConvLayer, ...],
   import jax.numpy as jnp
 
   def run(inputs):
-    ch = oracle.characterize_batch(None, layers, xp=jnp, inputs=inputs)
+    ch = oracle.characterize_batch(None, layers, xp=jnp,
+                                   inputs=_with_variations(inputs, jnp))
     full = (ch.latency_s, ch.power_mw, ch.area_mm2)
     if plan is None:
       return full
@@ -477,7 +488,7 @@ def make_table_fn(plan: Optional[DevicePlan]) -> Callable:
 
   def run(inputs, layer_cols, counts):
     ch = oracle.characterize_table(None, layer_cols, counts, xp=jnp,
-                                   inputs=inputs)
+                                   inputs=_with_variations(inputs, jnp))
     full = (ch.latency_s, ch.power_mw, ch.area_mm2)
     if plan is None:
       return full
@@ -502,8 +513,9 @@ def make_joint_fn(plan: Optional[DevicePlan]) -> Callable:
   import jax.numpy as jnp
 
   def run(inputs, unique_cols, slot_ids, valid, accs):
-    ch = oracle.characterize_joint_dedup(None, unique_cols, slot_ids, valid,
-                                         xp=jnp, inputs=inputs)
+    ch = oracle.characterize_joint_dedup(
+        None, unique_cols, slot_ids, valid, xp=jnp,
+        inputs=_with_variations(inputs, jnp))
     full = (ch.latency_s, ch.power_mw, ch.area_mm2)
     if plan is None:
       return full

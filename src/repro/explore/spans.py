@@ -15,7 +15,8 @@ thread, if one is.
 bind the submitting thread's (``bind``).  ``Recorder.meta()`` is what
 lands in ``StreamResult.meta``: ``self_s_<span>``,
 ``n_compiles_<span>`` and ``compile_s_<span>`` for every span below,
-and ``bytes_to_device``.
+``bytes_to_device``, and ``rows_var_on_device`` (result rows whose
+variation columns a device program derived, not the host).
 
 One ``jax.monitoring`` listener credits each XLA backend compile to the
 innermost span open on the thread that compiles (to ``stream`` when
@@ -59,6 +60,7 @@ class Recorder:
     self.n_compiles = dict.fromkeys(SPANS, 0)
     self.compile_s = dict.fromkeys(SPANS, 0.0)
     self.bytes_to_device = 0
+    self.rows_var_on_device = 0
     self.device_compiles: Dict[int, int] = {}
 
   def meta(self) -> Dict[str, float]:
@@ -69,6 +71,7 @@ class Recorder:
         out["n_compiles_" + name] = float(self.n_compiles[name])
         out["compile_s_" + name] = self.compile_s[name]
       out["bytes_to_device"] = float(self.bytes_to_device)
+      out["rows_var_on_device"] = float(self.rows_var_on_device)
     return out
 
   def compiles_per_device(self, n_devices: int) -> List[float]:
@@ -230,3 +233,12 @@ def count_bytes(n: int) -> None:
   if rec is not None:
     with rec.lock:
       rec.bytes_to_device += int(n)
+
+
+def count_var_rows(n: int) -> None:
+  """Add ``n`` result rows whose variation columns the device derives
+  to the bound recorder."""
+  rec = _TLS.recorder
+  if rec is not None:
+    with rec.lock:
+      rec.rows_var_on_device += int(n)

@@ -19,6 +19,7 @@ jax = pytest.importorskip("jax")
 
 from repro.core.cnn import SEARCH_SPACE, ArchChoice
 from repro.core.dataflow import LayerStack
+from repro.core.pe import PAPER_PE_TYPES
 from repro.core.workloads import get_network
 from repro.explore import (DesignSpace, ExplorationSession,
                            VectorOracleBackend)
@@ -135,6 +136,61 @@ def _assert_frames_equal(a, b, ctx=""):
   assert set(a.extra) == set(b.extra), ctx
   for k in a.extra:
     assert np.array_equal(a.extra[k], b.extra[k]), (ctx, k)
+
+
+@pytest.fixture(scope="module")
+def fused_programs(layers):
+  """The fused plain, table and joint programs, jitted once for every
+  case of the test below (its tables share one row count)."""
+  from repro.explore.device import (build_plan, make_eval_fn, make_joint_fn,
+                                    make_table_fn)
+  plan = build_plan(_reducers(), joint=False)
+  jplan = build_plan(_joint_reducers(), joint=True)
+  return (jax.jit(make_eval_fn(tuple(layers), plan)),
+          jax.jit(make_table_fn(plan)), jax.jit(make_joint_fn(jplan)))
+
+
+@pytest.mark.parametrize("types", [(t,) for t in PAPER_PE_TYPES]
+                         + [PAPER_PE_TYPES], ids=list(PAPER_PE_TYPES)
+                         + ["mixed"])
+def test_device_variations_are_bit_identical(types, layers, stack, space,
+                                             fused_programs):
+  """The x64 bundle ships the variation chain's keys, not its columns:
+  the traced chain equals the host's bit for bit, and so does every
+  fused program fed that bundle, against the host bundle and numpy."""
+  import jax.numpy as jnp
+  from repro.core import oracle
+  from repro.core.dataflow import layer_table
+  from repro.core.table import ConfigTable
+  per_type = 24 // len(types)
+  table = ConfigTable.concat([space.sample_type_table(t, per_type, seed=i)
+                              for i, t in enumerate(types)])
+  table.bandwidth_gbps = table.bandwidth_gbps + 0.37  # not a whole number
+  keyed = oracle.batch_inputs(table, device_variations=True)
+  host = oracle.batch_inputs(table)
+  assert keyed["var_keys"].dtype == np.uint64
+  assert not {"var_" + s for s, _ in oracle.VARIATIONS} & set(keyed)
+  plain, tabled, joint = fused_programs
+  cols, counts = layer_table(tuple(layers))
+  unique_cols, slot_ids = stack.dedup_slots()
+  joint_args = (unique_cols, slot_ids, stack.valid,
+                np.linspace(0.5, 0.9, stack.n_archs))
+  with jax.enable_x64(True):
+    derived = jax.jit(lambda c: oracle.variation_columns(c, jnp))(keyed)
+    runs = [(plain(b), tabled(b, cols, counts), joint(b, *joint_args))
+            for b in (keyed, host)]
+  for salt, pct in oracle.VARIATIONS:
+    assert np.array_equal(np.asarray(derived["var_" + salt]),
+                          oracle._variation_batch(table, salt, pct)), salt
+  got, want = jax.tree_util.tree_map(np.asarray, runs)
+  assert jax.tree_util.tree_all(jax.tree_util.tree_map(np.array_equal,
+                                                       got, want))
+  numpy_out = (oracle.characterize_batch(table, layers),
+               oracle.characterize_table(table, cols, counts),
+               oracle.characterize_joint(table, stack))
+  for (full, _), ch in zip(got, numpy_out):
+    for out, col in zip(full, METRICS):
+      assert np.array_equal(out, getattr(ch, col)), col
 
 
 class TestFusedReducers:

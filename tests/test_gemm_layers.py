@@ -311,7 +311,8 @@ def test_layer_table_copy_is_counted_in_place():
   cols, counts = layer_table(tuple(layers))
   table_bytes = sum(v.nbytes for v in cols.values()) + counts.nbytes
   inputs = sum(v.nbytes for v in oracle.batch_inputs(
-      DesignSpace().sample_table(8, seed=4)).values())  # 32 rows
+      DesignSpace().sample_table(8, seed=4),  # 32 rows
+      device_variations=True).values())
   assert res.meta["n_chunks"] == 4  # one chunk of 32 points per PE type
   assert res.meta["bytes_to_device"] == 4 * (table_bytes + inputs)
 

@@ -6,7 +6,10 @@
     self times inside the ``stream`` span add up to the sweep's seconds;
     the spans change no front;
   * compiles are credited to the span that makes them: survivors are cut
-    on the host, so a survivor count new to the process compiles nothing.
+    on the host, so a survivor count new to the process compiles nothing;
+  * ``rows_var_on_device`` counts the rows whose variation columns an x64
+    program derived: every point of such a sweep, none on float32 or
+    numpy.
 """
 import threading
 
@@ -162,6 +165,36 @@ def test_sweep_meta_carries_every_span(kind, layers, arch_accs):
   host = want.meta
   assert host["self_s_place"] == host["bytes_to_device"] == 0.0
   assert host["self_s_stream"] > 0.0
+
+
+@pytest.mark.parametrize("backend_kw", [{"jit": True},
+                                        {"jit": True, "precision": "float32"},
+                                        {}], ids=["x64", "float32", "numpy"])
+@pytest.mark.parametrize("kind", ["plain", "joint"])
+def test_rows_var_on_device_counts_the_sweep_on_x64(kind, backend_kw, layers,
+                                                    arch_accs, monkeypatch):
+  """An x64 program derives every row's variation columns itself, so its
+  sweep's counter equals the sweep's points; float32 (no 64-bit
+  integers) and numpy sweeps take the host's, and count none.  The
+  host's share of the bundle keeps one ``batch_inputs`` span a chunk."""
+  opened = []
+
+  class Counted(spans.span):
+    __slots__ = ()
+
+    def __enter__(self):
+      opened.append(self.name)
+      return super().__enter__()
+
+  monkeypatch.setattr(spans, "span", Counted)
+  backend = VectorOracleBackend(**backend_kw)
+  res = plain(backend, layers, 6) if kind == "plain" \
+      else joint(backend, arch_accs, 6)
+  x64 = backend_kw == {"jit": True}
+  assert res.n_rows > 0
+  assert res.meta["rows_var_on_device"] == (res.n_rows if x64 else 0)
+  if backend.jit:
+    assert opened.count("batch_inputs") == res.meta["n_chunks"] > 1
 
 
 @pytest.mark.parametrize("kind", ["plain", "joint"])

@@ -12,10 +12,16 @@ inputs, same flags as on the chip — at a reduced block:
   * the fused plain program (2-D Pareto + top-100) for all 21 resnet20
     layers at 4,096 rows; the real chunk is 65,536 rows.
 
+Both take the x64 bundle, whose variation columns the program derives
+from uint64 keys: the chip compiles the f64 -> u64 convert of the
+integer knobs, but no f64 -> u64 bitcast, so the bandwidth's bit pattern
+arrives from the host among the keys.
+
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and test workers import every file.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -76,11 +82,21 @@ def _on_chip(a, one_chip, rows=None):
 
 
 def _inputs_bundle():
-  """A real ``oracle.batch_inputs`` bundle: its keys and dtypes are what
-  the chip program receives."""
+  """A real x64 ``oracle.batch_inputs`` bundle: its keys and dtypes are
+  what the chip program receives."""
   space = DesignSpace()
   return oracle.batch_inputs(space.sample_type_table(space.pe_types[0], 8,
-                                                     seed=3))
+                                                     seed=3),
+                             device_variations=True)
+
+
+def _plain_program():
+  plan = device_lib.build_plan(
+      {"pareto": ParetoAccumulator(),
+       "top": TopKAccumulator(100, by="energy_mj")}, joint=False)
+  layers = tuple(get_network("resnet20"))
+  assert len(layers) == 21
+  return device_lib.make_eval_fn(layers, plan)
 
 
 def _compile(fn, args):
@@ -119,12 +135,26 @@ def test_fused_joint_program_compiles_for_v5e(one_chip):
 
 
 def test_fused_plain_program_compiles_for_v5e(one_chip):
-  plan = device_lib.build_plan(
-      {"pareto": ParetoAccumulator(),
-       "top": TopKAccumulator(100, by="energy_mj")}, joint=False)
-  layers = tuple(get_network("resnet20"))
-  assert len(layers) == 21
-  compiled = _compile(device_lib.make_eval_fn(layers, plan), (
+  compiled = _compile(_plain_program(), (
       {k: _on_chip(v, one_chip, PLAIN_ROWS)
        for k, v in _inputs_bundle().items()},))
   _check(compiled)
+
+
+def test_variation_keys_arrive_as_uint64_with_no_bitcast(one_chip):
+  """The chip's compiler refuses ``bitcast_convert_type(f64 -> u64)``:
+  the keys it cannot convert come from the host as one uint64 array, and
+  the program converts, never bitcasts, a float64 column."""
+  import jax
+  bundle = _inputs_bundle()
+  assert bundle["var_keys"].dtype == np.uint64
+  assert bundle["var_keys"].shape == (8, 2)
+  with jax.enable_x64(True):
+    text = jax.jit(_plain_program()).lower(
+        {k: _on_chip(v, one_chip, PLAIN_ROWS)
+         for k, v in bundle.items()}).as_text()
+  main = re.search(r"func\.func public @main\(.*", text).group(0)
+  assert f"tensor<{PLAIN_ROWS}x2xui64>" in main
+  assert re.search(r"stablehlo\.convert %\w+ : \(tensor<\d+xf64>\) -> "
+                   r"tensor<\d+xui64>", text)
+  assert not re.search(r"bitcast_convert.*f64.*->.*ui64", text)
