@@ -6,15 +6,13 @@ Run from the repository root:
   python chip_smoke.py            one TPU chip: exactness probe, plain DSE,
                                   joint co-exploration, guided search
   python chip_smoke.py --chips 4  a four-chip host: the joint sweep through a
-                                  DevicePool, and a one-shot co_evaluate_table
-                                  sharded over every chip by shard_map
+                                  DevicePool
 
 Every phase goes through the entry points a user calls
-(``ExplorationSession.explore`` / ``co_explore`` / ``optimize``, or
-``VectorOracleBackend.co_evaluate_table``) on ``VectorOracleBackend(jit=True)``
-and is compared with the same call on the numpy backend.  Earlier lines
-report each phase's compile seconds, wall seconds, rows/s, transfer and
-fallback counters, and the comparison.  The last line of stdout is one JSON
+(``ExplorationSession.explore`` / ``co_explore`` / ``optimize``) on
+``VectorOracleBackend(jit=True)`` and is compared with the same call on the
+numpy backend.  Earlier lines report each phase's compile seconds, wall
+seconds, rows/s, transfer and fallback counters, and the comparison.  The last line of stdout is one JSON
 object, ``{"ok": ..., "device": {"platform", "kind", "count"}}``; the exit
 code is 0 only when ``ok`` is true.  There is no CPU fallback: without a TPU
 the script fails.  JAX's persistent compile cache is placed by
@@ -53,7 +51,6 @@ JOINT_CHUNK = 262_144
 JOINT_IMAGE_SIZE = 16
 TOP_K = 100
 SEARCH_POPULATION, SEARCH_GENERATIONS = 32, 12
-SHARD_ARCHS = 40                    # four chips: 40 archs x 2,500 HW rows
 
 
 def log(msg: str) -> None:
@@ -354,55 +351,6 @@ class SearchPhase:
     return report(self.name, wall, cs, nc, res, ref_wall, comps, checks)
 
 
-class ShardedPhase:
-  """A one-shot joint evaluation whose HW rows VectorOracleBackend shards
-  over every visible device with shard_map."""
-  name = "sharded_co_evaluate"
-
-  def __init__(self):
-    from repro.core.dataflow import LayerStack
-    from repro.core.supernet import arch_to_layers
-    from repro.explore import DesignSpace, VectorOracleBackend
-    archs = [a for a, _ in supernet_archs()[:SHARD_ARCHS]]
-    self.stack = LayerStack.from_layer_lists(
-        [arch_to_layers(a, image_size=JOINT_IMAGE_SIZE) for a in archs])
-    space = DesignSpace()
-    self.hw = space.sample_type_table(space.pe_types[0], JOINT_HW_PER_TYPE,
-                                      seed=3)
-    self.backend = VectorOracleBackend(chunk_size=JOINT_CHUNK, jit=True)
-
-  def warm_jobs(self):
-    return [lambda: self.backend.co_evaluate_table(self.hw, self.stack)]
-
-  def run(self, exact: bool) -> bool:
-    from repro.explore import VectorOracleBackend
-    from repro.explore.fleet import visible_devices
-    dev, wall, cs, nc = timed(
-        lambda: self.backend.co_evaluate_table(self.hw, self.stack))
-    ref = VectorOracleBackend(chunk_size=JOINT_CHUNK).co_evaluate_table(
-        self.hw, self.stack)
-    identical = all(np.array_equal(getattr(dev, c), getattr(ref, c))
-                    for c in METRICS)
-    max_rel = max(float(np.max(np.abs(getattr(dev, c) / getattr(ref, c)
-                                      - 1.0))) for c in METRICS)
-    # the devices the (cached) program's outputs live on: all of them, or
-    # the rows never left device 0
-    unique_cols, slot_ids = self.stack.dedup_slots()
-    with self.backend._x64():
-      inputs = self.backend._inputs(self.hw,
-                                    len(self.hw) * self.stack.n_archs)
-      out = self.backend._joint_fn()(inputs, unique_cols, slot_ids,
-                                     self.stack.valid, np.zeros(0))
-    n_out_devices = min(len(o.sharding.device_set) for o in out)
-    ok = (identical if exact else max_rel <= VALUE_REL_BOUND) \
-        and n_out_devices == len(visible_devices())
-    log(f"phase={self.name} ok={ok} wall_s={wall} compile_s={cs} "
-        f"n_compiles={nc} rows={len(dev)} rows_per_s={len(dev) / wall} "
-        f"output_devices={n_out_devices} values_identical={identical} "
-        f"max_rel={max_rel}")
-    return ok
-
-
 def run(chips: int) -> dict:
   import jax
 
@@ -438,7 +386,7 @@ def run(chips: int) -> dict:
     phases = [PlainPhase(), JointPhase(), SearchPhase()]
   else:
     from repro.explore import DevicePool
-    phases = [JointPhase(pool=DevicePool()), ShardedPhase()]
+    phases = [JointPhase(pool=DevicePool())]
   compile_ahead([job for p in phases for job in p.warm_jobs()])
   oks = [p.run(exact) for p in phases]
   compile_s, n_compiles = compile_totals()
@@ -450,7 +398,7 @@ def main() -> None:
   ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
   ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                   help="1 (default): the main path on one chip; 4: only the "
-                       "multi-device paths on a four-chip host")
+                       "DevicePool joint sweep on a four-chip host")
   args = ap.parse_args()
   try:
     result = run(args.chips)

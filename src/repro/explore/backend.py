@@ -10,7 +10,7 @@ Three implementations of the :class:`EvaluationBackend` protocol:
                        bounded-memory chunks via the ``*_batch`` formulas;
                        bit-identical to OracleBackend on the numpy path,
                        ~2 orders of magnitude faster, with an optional
-                       ``jax.jit`` / ``shard_map`` device path
+                       ``jax.jit`` device path
   PolynomialBackend    fast — QUIDAM's fit-once / evaluate-many polynomial
                        models (``repro.core.ppa``), with in-process fit
                        memoization and ``save``/``load`` to ``.npz`` so
@@ -174,8 +174,9 @@ class VectorOracleBackend:
   old approximate fast mode, with no 64-bit integers, so its variation
   columns come from the host.  Joint sweeps compile the distinct-layer
   factorization with the stack as a traced input, so one executable
-  serves every arch block of a streaming sweep.  When several devices
-  are visible, chunk rows shard across them via ``shard_map``.
+  serves every arch block of a streaming sweep.  A program runs on one
+  device: the default one, or the device a
+  :class:`repro.explore.fleet.DevicePool` pins the chunk to.
 
   The streaming engine additionally uses the ``*_pending`` entry points:
   chunks dispatch asynchronously (jax futures) and resolve later, and
@@ -374,44 +375,6 @@ class VectorOracleBackend:
     return jax.jit(fn, **kwargs)
 
   @staticmethod
-  def _shard_rows(fn, joint: bool):
-    """Shard the HW-row axis of a full (lat, pwr, area) program across
-    visible devices (identity for a single device).  Fused programs run
-    unsharded — their reductions are chunk-global; multi-device overlap
-    comes from the dispatch-ahead window instead."""
-    import jax
-    import jax.numpy as jnp
-    from repro.explore.fleet import visible_devices
-    devices = visible_devices()
-    if len(devices) <= 1:
-      return fn
-    from jax.sharding import Mesh, PartitionSpec as P
-    mesh = Mesh(np.asarray(devices), ("batch",))
-    out_specs = (P(None, "batch"), P("batch"), P("batch")) if joint \
-        else (P("batch"), P("batch"), P("batch"))
-
-    def rowwise(inputs, *rest):
-      return fn(inputs, *rest)
-
-    def padded(inputs, *rest):
-      n = next(iter(inputs.values())).shape[0]
-      pad = (-n) % len(devices)
-      in_specs = (P("batch"),) + tuple(P() for _ in rest)
-      sharded = jax.shard_map(rowwise, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
-      if pad:
-        inputs = {k: jnp.concatenate([jnp.asarray(v),
-                                      jnp.asarray(v[-1:]).repeat(pad, 0)])
-                  for k, v in inputs.items()}
-      l, p, a = sharded(inputs, *rest)
-      if joint:
-        return l[:, :n], p[:n], a[:n]
-      return l[:n], p[:n], a[:n]
-
-    from repro.explore.device import named
-    return named(padded, fn.__name__ + "_sharded")
-
-  @staticmethod
   def _pinned():
     """The fleet layer's thread-local device pin (None: default
     placement) — see :func:`repro.explore.fleet.pin`."""
@@ -424,10 +387,10 @@ class VectorOracleBackend:
     and a layer table) to the pinned device, so the jitted program
     executes there; with ``commit`` and no pin, to the default device,
     so that the copy is a stage of its own rather than part of the call.
-    Unpinned, uncommitted inputs stay on the host (the row-sharded
-    program splits them itself).  Must run inside the ``_x64`` context —
-    ``device_put`` canonicalizes dtypes, and float64 inputs would be
-    silently downcast outside it."""
+    Unpinned, uncommitted inputs stay on the host (the jitted call
+    copies them to the default device).  Must run inside the ``_x64``
+    context — ``device_put`` canonicalizes dtypes, and float64 inputs
+    would be silently downcast outside it."""
     if dev is None and not commit:
       return inputs
     import jax
@@ -436,54 +399,32 @@ class VectorOracleBackend:
                             for v in jax.tree_util.tree_leaves(inputs)))
       return jax.device_put(inputs, dev)
 
-  def _eval_fn(self, layers: Tuple[ConvLayer, ...], plan=None,
-               pinned: bool = False):
+  def _eval_fn(self, layers: Tuple[ConvLayer, ...], plan=None):
     from repro.explore import device as device_lib
-    pinned = bool(pinned) and plan is None  # fused programs never shard
+    return self._cached_fn(
+        ("eval", layers, plan, self.precision),
+        lambda: self._jit(device_lib.make_eval_fn(layers, plan)))
 
-    def build():
-      fn = device_lib.make_eval_fn(layers, plan)
-      if plan is None and not pinned:
-        fn = self._shard_rows(fn, joint=False)
-      return self._jit(fn)
-
-    return self._cached_fn(("eval", layers, plan, self.precision, pinned),
-                           build)
-
-  def _table_fn(self, plan=None, pinned: bool = False):
+  def _table_fn(self, plan=None):
     from repro.explore import device as device_lib
-    pinned = bool(pinned) and plan is None  # fused programs never shard
+    return self._cached_fn(
+        ("eval_table", plan, self.precision),
+        lambda: self._jit(device_lib.make_table_fn(plan)))
 
-    def build():
-      fn = device_lib.make_table_fn(plan)
-      if plan is None and not pinned:
-        fn = self._shard_rows(fn, joint=False)
-      return self._jit(fn)
-
-    return self._cached_fn(("eval_table", plan, self.precision, pinned),
-                           build)
-
-  def _program(self, layers: Tuple[ConvLayer, ...], plan=None,
-               pinned: bool = False):
+  def _program(self, layers: Tuple[ConvLayer, ...], plan=None):
     """The plain-sweep program for ``layers`` and the arguments it takes
     after the input columns: a network with GEMM layers runs the table
     program over its layer table, a conv network the program its layers
     are baked into."""
     if any(isinstance(l, GemmLayer) for l in layers):
-      return self._table_fn(plan, pinned), _table_of(layers)
-    return self._eval_fn(layers, plan, pinned), ()
+      return self._table_fn(plan), _table_of(layers)
+    return self._eval_fn(layers, plan), ()
 
-  def _joint_fn(self, plan=None, pinned: bool = False):
+  def _joint_fn(self, plan=None):
     from repro.explore import device as device_lib
-    pinned = bool(pinned) and plan is None  # fused programs never shard
-
-    def build():
-      fn = device_lib.make_joint_fn(plan)
-      if plan is None and not pinned:
-        fn = self._shard_rows(fn, joint=True)
-      return self._jit(fn)
-
-    return self._cached_fn(("joint", plan, self.precision, pinned), build)
+    return self._cached_fn(
+        ("joint", plan, self.precision),
+        lambda: self._jit(device_lib.make_joint_fn(plan)))
 
   def _inputs(self, table: ConfigTable, n_rows: int) -> Dict:
     """A device program's input bundle for ``table``, whose chunk has
@@ -513,8 +454,8 @@ class VectorOracleBackend:
     inputs = self._inputs(chunk, len(chunk) * stack.n_archs)
     unique_cols, slot_ids = stack.dedup_slots() if dedup is None else dedup
     with self._x64():
-      # accs is only consumed by fused plans; an empty array keeps the
-      # arg pytree shard_map-friendly (None has no pytree leaves)
+      # accs is only consumed by fused plans; the joint program's
+      # signature still takes it, so an empty array stands in
       l, p, a = self._joint_fn()(inputs, unique_cols, slot_ids,
                                  stack.valid, np.zeros(0))
     return (np.asarray(jax.device_get(l), np.float64),
@@ -535,7 +476,7 @@ class VectorOracleBackend:
         inputs = self._inputs(table, len(table))
       dev = self._pinned()
       with self._x64():
-        fn, args = self._program(layers, pinned=dev is not None)
+        fn, args = self._program(layers)
         inputs, args = self._place((inputs, args), dev)
         with spans.span("launch"):
           out = fn(inputs, *args)
@@ -563,8 +504,8 @@ class VectorOracleBackend:
       with self._x64():
         inputs = self._place(inputs, dev)
         with spans.span("launch"):
-          out = self._joint_fn(pinned=dev is not None)(
-              inputs, unique_cols, slot_ids, stack.valid, np.zeros(0))
+          out = self._joint_fn()(inputs, unique_cols, slot_ids,
+                                 stack.valid, np.zeros(0))
 
     def finalize():
       lat, pwr, area = (np.asarray(jax.device_get(o), np.float64)

@@ -57,15 +57,14 @@ import dataclasses
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterable, Iterator, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
 from repro.core.seeding import derive_seed
 from repro.explore import spans
-from repro.explore.resilience import (ChunkError, ChunkTask, CircuitBreaker,
-                                      ResiliencePolicy, SweepJournal,
-                                      SweepKilled)
+from repro.explore.resilience import (ChunkTask, CircuitBreaker,
+                                      ResiliencePolicy, SweepKilled)
 from repro.train.fault_tolerance import StragglerMonitor
 
 
@@ -441,55 +440,14 @@ def _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead, resume_from,
                  journal_key, checkpoint_every):
   """The body of :func:`run_fleet`, inside its span."""
   # deferred: streaming imports fleet lazily too (pool= routing)
-  from repro.explore.streaming import (DISPATCH_AHEAD, StreamResult,
-                                       fold_chunk, new_counters)
+  from repro.explore.streaming import DISPATCH_AHEAD, SweepProgress
   if dispatch_ahead is None:
     dispatch_ahead = DISPATCH_AHEAD
   t0 = time.perf_counter()
   plan = policy.fault_plan if policy is not None else None
-  journal = None
-  done_chunks: set = set()
-  counters = new_counters()
-  n_resumed = 0
-  if resume_from is not None:
-    journal = resume_from if isinstance(resume_from, SweepJournal) \
-        else SweepJournal(resume_from)
-    state = journal.load_state(journal_key)
-    if state is not None:
-      done_chunks = set(state["done"])
-      for name, r in reducers.items():
-        r.restore(state["reducers"][name])
-      counters.update(state["counters"])
-      n_resumed = len(done_chunks)
-  base_retries = counters["n_retries"]
-  base_demotions = counters["n_demotions"]
+  progress = SweepProgress(reducers, policy, resume_from, journal_key,
+                           checkpoint_every)
   base_fleet = pool.counters()
-  since_ckpt = 0
-
-  def totals() -> Tuple[int, int]:
-    extra_r = policy.n_retries if policy is not None else 0
-    extra_d = policy.n_demotions if policy is not None else 0
-    return base_retries + extra_r, base_demotions + extra_d
-
-  def checkpoint(force: bool = False) -> None:
-    nonlocal since_ckpt
-    if journal is None:
-      return
-    since_ckpt += 1
-    if not force and since_ckpt < max(int(checkpoint_every), 1):
-      return
-    counters["n_retries"], counters["n_demotions"] = totals()
-    journal.record(journal_key, {
-        "done": set(done_chunks),
-        "reducers": {name: r.snapshot() for name, r in reducers.items()},
-        "counters": dict(counters)})
-    since_ckpt = 0
-
-  def fail(index, exc):
-    checkpoint(force=True)
-    if isinstance(exc, ChunkError):
-      raise exc
-    raise ChunkError(index, f"{type(exc).__name__}: {exc}") from exc
 
   def execute(task):
     if policy is not None:
@@ -507,23 +465,7 @@ def _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead, resume_from,
       out = out.resolve()
     return out
 
-  def finish_fold(index, result) -> None:
-    try:
-      with spans.at(index):
-        fold_chunk(reducers, counters, result)
-    except Exception as e:
-      fail(index, e)
-    done_chunks.add(index)
-    checkpoint()
-
-  def indexed(ts) -> Iterator[Tuple[int, ChunkTask]]:
-    for i, t in enumerate(ts):
-      index = getattr(t, "index", i)
-      if index in done_chunks:
-        continue
-      yield index, t
-
-  source = indexed(tasks)
+  source = progress.todo(tasks)
   queue: "deque" = deque()        # requeued (orphaned / replayed) chunks
   inflight: List[_Shard] = []
   # dev index -> [(chunk index, task, resolved result)] awaiting the
@@ -580,13 +522,13 @@ def _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead, resume_from,
       else:
         out = execute(task)
     except SweepKilled:
-      checkpoint(force=True)
+      progress.checkpoint(force=True)
       raise
     except Exception as e:
       if dev is not None:
         pool.checkin(dev)
         pool.record_failure(dev)
-      fail(index, e)
+      progress.fail(index, e)
     inflight.append(_Shard(index, task, dev, out, start,
                            immediate=not hasattr(out, "resolve"),
                            slow=slow, corrupt=corrupt))
@@ -617,7 +559,7 @@ def _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead, resume_from,
             spans.span("speculate"):
           out = execute(shard.task)
       except SweepKilled:
-        checkpoint(force=True)
+        progress.checkpoint(force=True)
         raise
       except Exception:
         # the speculation failed, the original is still in flight —
@@ -652,7 +594,7 @@ def _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead, resume_from,
       matched = _results_match(result, reference)
     if matched:
       for i, _, r in buf:
-        finish_fold(i, r)
+        progress.fold(i, r)
       buf.clear()
       return
     pool.note_corruption()
@@ -680,15 +622,15 @@ def _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead, resume_from,
     except SweepKilled:
       if shard.dev is not None:
         pool.checkin(shard.dev)
-      checkpoint(force=True)
+      progress.checkpoint(force=True)
       raise
     except Exception as e:
       if shard.dev is not None:
         pool.checkin(shard.dev)
         pool.record_failure(shard.dev)
-      fail(shard.index, e)
+      progress.fail(shard.index, e)
     if shard.dev is None:
-      finish_fold(shard.index, result)
+      progress.fold(shard.index, result)
       return
     pool.checkin(shard.dev)
     pool.record_latency(shard.dev, time.perf_counter() - shard.t0)
@@ -700,7 +642,7 @@ def _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead, resume_from,
           (shard.index, shard.task, result))
       validate(shard.dev)
     else:
-      finish_fold(shard.index, result)
+      progress.fold(shard.index, result)
 
   while True:
     while len(inflight) < window_cap:
@@ -721,28 +663,12 @@ def _drain_fleet(tasks, reducers, pool, policy, dispatch_ahead, resume_from,
       continue  # a failed validation requeues chunks
     break
 
-  checkpoint(force=True)
+  progress.checkpoint(force=True)
   seconds = time.perf_counter() - t0
-  n_retries, n_demotions = totals()
-  fleet_now = pool.counters()
-  meta = {"seconds": seconds, "workers": 1.0,
-          "n_chunks": float(counters["n_chunks"]),
-          "rows_transferred": float(counters["n_transferred"]),
-          "n_retries": float(n_retries),
-          "n_demotions": float(n_demotions),
-          "n_resumed_chunks": float(n_resumed),
-          "n_overflows": float(counters["n_overflows"])}
+  fleet_now, pool_meta = pool.counters(), pool.meta()
   # per-run deltas of the (pool-lifetime) mitigation counters
-  meta.update({k: float(fleet_now[k] - base_fleet[k]) for k in fleet_now})
-  pool_meta = pool.meta()
-  for k in ("fleet_devices", "fleet_device_states",
-            "n_quarantined_devices", "fleet_device_chunks",
-            "fleet_device_ewma_s"):
-    meta[k] = pool_meta[k]
-  if policy is not None:
-    meta["n_leaked_watchdogs"] = float(policy.watchdogs.n_live())
-    if policy.breaker is not None:
-      meta.update(policy.breaker.meta())
-  return StreamResult(
-      results={name: r.result() for name, r in reducers.items()},
-      n_rows=counters["n_rows"], seconds=seconds, meta=meta)
+  extra = {k: float(fleet_now[k] - base_fleet[k]) for k in fleet_now}
+  extra.update((k, pool_meta[k]) for k in (
+      "fleet_devices", "fleet_device_states", "n_quarantined_devices",
+      "fleet_device_chunks", "fleet_device_ewma_s"))
+  return progress.result(seconds, 1.0, **extra)

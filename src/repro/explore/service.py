@@ -72,11 +72,10 @@ from repro.explore.store import (ResultStore, _explore_manifest,
                                  co_explore_result_key, explore_result_key,
                                  find_delta_base)
 from repro.explore.streaming import (DISPATCH_AHEAD, Reducer, StreamResult,
-                                     co_explore_sweep_key, co_explore_tasks,
-                                     default_co_reducers,
+                                     SweepProgress, co_explore_sweep_key,
+                                     co_explore_tasks, default_co_reducers,
                                      default_explore_reducers,
-                                     explore_sweep_key, explore_tasks,
-                                     fold_chunk, new_counters)
+                                     explore_sweep_key, explore_tasks)
 
 # how long SessionHandle.result / service joins wait per condition poll —
 # every wait in this module is bounded (the ROB002 idiom)
@@ -201,15 +200,12 @@ class _Session:
   """Scheduler-internal state shared by sweep and search sessions."""
 
   def __init__(self, sid: int, kind: str, policy: ResiliencePolicy,
-               deadline: Optional[Deadline], chunk_budget: Optional[int],
-               journal: Optional[SweepJournal], journal_key: str):
+               deadline: Optional[Deadline], chunk_budget: Optional[int]):
     self.sid = sid
     self.kind = kind
     self.policy = policy
     self.deadline = deadline
     self.chunk_budget = chunk_budget
-    self.journal = journal
-    self.journal_key = journal_key
     self.state = "queued"
     self.cancel_requested = False
     self.error: Optional[BaseException] = None
@@ -229,7 +225,9 @@ class _Session:
 
 
 class _SweepSession(_Session):
-  """An explore/co-explore sweep interleaved chunk-by-chunk."""
+  """An explore/co-explore sweep interleaved chunk-by-chunk.  Its
+  :class:`SweepProgress` resumes from the store's journal on
+  construction and checkpoints to its append log."""
 
   def __init__(self, sid: int, kind: str, policy: ResiliencePolicy,
                deadline: Optional[Deadline], chunk_budget: Optional[int],
@@ -237,64 +235,24 @@ class _SweepSession(_Session):
                reducers: Dict[str, Reducer], tasks,
                dispatch_ahead: int, checkpoint_every: int,
                result_key: str = "", manifest=None):
-    super().__init__(sid, kind, policy, deadline, chunk_budget, journal,
-                     journal_key)
-    self.reducers = reducers
-    self.task_iter = iter(tasks)
+    super().__init__(sid, kind, policy, deadline, chunk_budget)
+    self.progress = SweepProgress(
+        reducers, policy, journal, journal_key, checkpoint_every,
+        write=journal.append if journal is not None else None)
+    self.todo = self.progress.todo(tasks)
     self.next_task: Optional[ChunkTask] = None
-    self.exhausted = False
     self.window: deque = deque()
     self.dispatch_ahead = max(int(dispatch_ahead), 0)
-    self.checkpoint_every = max(int(checkpoint_every), 1)
     self.result_key = result_key
     self.manifest = manifest
-    self.counters = new_counters()
-    self.done_chunks: set = set()
-    self.n_resumed = 0
-    self._since_ckpt = 0
-    self._base_retries = 0
-    self._base_demotions = 0
-
-  def adopt_checkpoint(self, state: Dict[str, object]) -> None:
-    self.done_chunks = set(state["done"])
-    for name, r in self.reducers.items():
-      r.restore(state["reducers"][name])
-    self.counters.update(state["counters"])
-    self.n_resumed = len(self.done_chunks)
-    self._base_retries = self.counters["n_retries"]
-    self._base_demotions = self.counters["n_demotions"]
-
-  def totals(self) -> Tuple[int, int]:
-    return (self._base_retries + self.policy.n_retries,
-            self._base_demotions + self.policy.n_demotions)
-
-  def checkpoint(self, force: bool = False) -> None:
-    if self.journal is None:
-      return
-    self._since_ckpt += 1
-    if not force and self._since_ckpt < self.checkpoint_every:
-      return
-    r, d = self.totals()
-    self.counters["n_retries"], self.counters["n_demotions"] = r, d
-    self.journal.append(self.journal_key, {
-        "done": set(self.done_chunks),
-        "reducers": {n: r_.snapshot() for n, r_ in self.reducers.items()},
-        "counters": dict(self.counters)})
-    self._since_ckpt = 0
 
   def pull_task(self) -> Optional[ChunkTask]:
     """Next not-yet-folded task, or None when the sweep is exhausted."""
     if self.next_task is not None:
       task, self.next_task = self.next_task, None
       return task
-    while not self.exhausted:
-      task = next(self.task_iter, None)
-      if task is None:
-        self.exhausted = True
-        return None
-      if task.index not in self.done_chunks:
-        return task
-    return None
+    item = next(self.todo, None)
+    return None if item is None else item[1]
 
 
 class _EvalRequest:
@@ -335,9 +293,8 @@ class _SearchSession(_Session):
 
   def __init__(self, sid: int, policy: ResiliencePolicy,
                deadline: Optional[Deadline], chunk_budget: Optional[int],
-               journal: Optional[SweepJournal], run_search):
-    super().__init__(sid, "search", policy, deadline, chunk_budget,
-                     journal, "")
+               run_search):
+    super().__init__(sid, "search", policy, deadline, chunk_budget)
     self._run_search = run_search  # (proxy backend) -> StreamResult
     self.requests: deque = deque()
     self.thread: Optional[threading.Thread] = None
@@ -468,7 +425,7 @@ class ExplorationService:
       r.restore(state["reducers"][name])
     res = _cached_result(reducers, state, time.perf_counter() - t0)
     s = _Session(self._next_sid(), kind, ResiliencePolicy(retry=self.retry),
-                 None, None, None, "")
+                 None, None)
     res.meta["session"] = float(s.sid)
     s.finalize("done", result=res)
     self.stats["n_admitted"] += 1
@@ -544,10 +501,6 @@ class ExplorationService:
                         self.dispatch_ahead, self.checkpoint_every,
                         result_key=result_key, manifest=manifest)
       s.meta_extra = meta_extra
-      if journal is not None:
-        ckpt = journal.load_state(journal_key)
-        if ckpt is not None:
-          s.adopt_checkpoint(ckpt)
       return self._enqueue(s)
 
   # -- submission: co-exploration -------------------------------------------
@@ -587,10 +540,6 @@ class ExplorationService:
                         chunk_budget, journal, journal_key, reducers, tasks,
                         self.dispatch_ahead, self.checkpoint_every,
                         result_key=result_key)
-      if journal is not None:
-        ckpt = journal.load_state(journal_key)
-        if ckpt is not None:
-          s.adopt_checkpoint(ckpt)
       return self._enqueue(s)
 
   # -- submission: guided search --------------------------------------------
@@ -626,8 +575,7 @@ class ExplorationService:
             resume_from=resume_from, checkpoint_every=ckpt_every)
 
       s = _SearchSession(self._next_sid(), self._session_policy(deadline),
-                         deadline, chunk_budget,
-                         resume_from, run_search)
+                         deadline, chunk_budget, run_search)
       return self._enqueue(s)
 
   # -- the scheduler --------------------------------------------------------
@@ -651,7 +599,7 @@ class ExplorationService:
         continue
       if isinstance(s, _SweepSession):
         try:
-          s.checkpoint(force=True)
+          s.progress.checkpoint(force=True)
         except Exception:
           # best-effort on the way down, but never silent
           self.stats["n_checkpoint_errors"] = \
@@ -703,12 +651,12 @@ class ExplorationService:
 
   def _step_sweep(self, s: _SweepSession) -> bool:
     if s.cancel_requested:
-      s.checkpoint(force=True)
+      s.progress.checkpoint(force=True)
       self._abandon_window(s)
       s.finalize("cancelled", error=SessionCancelled(s.sid))
       return True
     if s.deadline is not None and s.deadline.expired():
-      s.checkpoint(force=True)
+      s.progress.checkpoint(force=True)
       self._abandon_window(s)
       s.finalize("expired", error=DeadlineExceeded(s.sid, s.deadline))
       return True
@@ -723,7 +671,7 @@ class ExplorationService:
       return True
     if s.chunk_budget is not None and s.n_dispatched >= s.chunk_budget:
       s.next_task = task  # not consumed: a resume re-pulls it
-      s.checkpoint(force=True)
+      s.progress.checkpoint(force=True)
       self._abandon_window(s)
       s.finalize("failed", error=BudgetExhausted(s.sid, s.chunk_budget))
       return True
@@ -742,13 +690,16 @@ class ExplorationService:
     except SweepKilled:
       if dev is not None:
         self.pool.checkin(dev)
-      s.checkpoint(force=True)
+      s.progress.checkpoint(force=True)
       raise
     except Exception as e:
       if dev is not None:
         self.pool.checkin(dev)
         self.pool.record_failure(dev)
-      self._fail_sweep(s, task.index, e)
+      try:
+        s.progress.fail(task.index, e)
+      except ChunkError as err:
+        self._fail_sweep(s, err)
       return True
     s.n_dispatched += 1
     if hasattr(out, "resolve"):
@@ -772,10 +723,9 @@ class ExplorationService:
     index, pending, dev, t_dispatch = s.window.popleft()
     try:
       self._fold(s, index, pending)
-    except SweepKilled:
+    except SweepKilled:  # the journal is already flushed
       if dev is not None:
         self.pool.checkin(dev)
-      s.checkpoint(force=True)
       raise
     if dev is not None:
       self._release(dev, t_dispatch, ok=s.state != "failed")
@@ -783,52 +733,29 @@ class ExplorationService:
 
   def _fold(self, s: _SweepSession, index: int, result) -> None:
     try:
-      fold_chunk(s.reducers, s.counters, result)
-    except SweepKilled:
-      raise
-    except Exception as e:
-      self._fail_sweep(s, index, e)
-      return
-    s.done_chunks.add(index)
-    s.checkpoint()
+      s.progress.fold(index, result)
+    except ChunkError as err:
+      self._fail_sweep(s, err)
 
-  def _fail_sweep(self, s: _SweepSession, index: int,
-                  exc: Exception) -> None:
-    s.checkpoint(force=True)
+  def _fail_sweep(self, s: _SweepSession, err: ChunkError) -> None:
     self._abandon_window(s)
-    err = exc if isinstance(exc, ChunkError) \
-        else ChunkError(index, f"{type(exc).__name__}: {exc}")
-    err.__cause__ = exc
     s.finalize("failed", error=err)
 
   def _complete_sweep(self, s: _SweepSession) -> None:
-    s.checkpoint(force=True)
+    s.progress.checkpoint(force=True)
     seconds = time.perf_counter() - (s.t0 or time.perf_counter())
-    n_retries, n_demotions = s.totals()
-    meta = {"seconds": seconds, "workers": 1.0,
-            "n_chunks": float(s.counters["n_chunks"]),
-            "rows_transferred": float(s.counters["n_transferred"]),
-            "n_retries": float(n_retries),
-            "n_demotions": float(n_demotions),
-            "n_resumed_chunks": float(s.n_resumed),
-            "n_overflows": float(s.counters["n_overflows"]),
-            "session": float(s.sid),
-            "service_slots": float(len(self.slots))}
-    meta["n_leaked_watchdogs"] = float(s.policy.watchdogs.n_live())
-    meta.update(s.meta_extra)
-    if self.breaker is not None:
-      meta.update(self.breaker.meta())
+    res = s.progress.result(seconds, 1.0, session=float(s.sid),
+                            service_slots=float(len(self.slots)),
+                            **s.meta_extra)
     if self.pool is not None:
-      meta.update(self.pool.meta())
-    res = StreamResult(
-        results={n: r.result() for n, r in s.reducers.items()},
-        n_rows=s.counters["n_rows"], seconds=seconds, meta=meta)
+      res.meta.update(self.pool.meta())
     if "n_base_rows" in s.meta_extra:
       res.meta["n_delta_rows"] = float(res.n_rows)
       res.n_rows += int(s.meta_extra["n_base_rows"])
     if self.store is not None and s.result_key:
       self.store.put_final(s.result_key,
-                           _snapshot_state(s.reducers, res), s.manifest)
+                           _snapshot_state(s.progress.reducers, res),
+                           s.manifest)
     s.finalize("done", result=res)
 
   # -- search stepping ------------------------------------------------------

@@ -55,7 +55,7 @@ from repro.explore.resilience import (ResiliencePolicy, SweepJournal,
                                       reducers_fingerprint,
                                       space_fingerprint, sweep_key)
 from repro.explore.space import DesignSpace
-from repro.explore.streaming import (Reducer, StreamResult,
+from repro.explore.streaming import (Reducer, StreamResult, SweepProgress,
                                      default_co_reducers,
                                      default_explore_reducers,
                                      default_workers, explore_tasks,
@@ -318,16 +318,12 @@ def _snapshot_state(reducers: Dict[str, Reducer],
 
 def _cached_result(reducers: Dict[str, Reducer], state: Dict[str, object],
                    seconds: float) -> StreamResult:
-  n_chunks = float(state.get("n_chunks", 0))
-  n_rows = int(state.get("n_rows", 0))
-  return StreamResult(
-      results={n: r.result() for n, r in reducers.items()},
-      n_rows=n_rows, seconds=seconds,
-      meta={"seconds": seconds, "workers": 0.0, "n_chunks": n_chunks,
-            "rows_transferred": 0.0,
-            "n_retries": 0.0, "n_demotions": 0.0,
-            "n_resumed_chunks": n_chunks, "n_overflows": 0.0,
-            "store_hit": 1.0})
+  """A store hit's result: every chunk counts as resumed, none moved."""
+  progress = SweepProgress(reducers)
+  progress.counters["n_chunks"] = int(state.get("n_chunks", 0))
+  progress.counters["n_rows"] = int(state.get("n_rows", 0))
+  progress.n_resumed = progress.counters["n_chunks"]
+  return progress.result(seconds, 0.0, store_hit=1.0)
 
 
 def _restore_delta_base(store: ResultStore, base_key: str,

@@ -15,10 +15,15 @@ sampling -> evaluation -> reduction into a bounded-memory pipeline:
               ``co_evaluate_table`` exactly as the one-shot path would,
               optionally on a thread pool (the numpy formulas release
               the GIL; the jax ``jit=True`` path keeps one submitting
-              thread — each chunk already spans all devices via
-              shard_map)
+              thread with a dispatch-ahead window on one device, and a
+              :class:`~repro.explore.fleet.DevicePool` spreads chunks
+              over several)
   reduction   online accumulators fold ``(chunk frame, global row ids)``
               blocks and keep only the survivors
+  progress    :class:`SweepProgress` owns a sweep's resume, folds,
+              checkpoints and run stats for every engine that drains
+              chunks (:func:`run_stream`, :func:`repro.explore.fleet
+              .run_fleet`, the exploration service)
 
 Every accumulator is **chunk-order invariant** and emits survivors in
 global row order, so streaming results are bit-identical (numpy path) to
@@ -52,8 +57,8 @@ import os
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor, wait
-from typing import (Callable, Dict, Iterable, Iterator, Optional, Sequence,
-                    Tuple)
+from typing import (Callable, Dict, Iterable, Iterator, NoReturn, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 
@@ -61,7 +66,7 @@ from repro.explore import spans
 from repro.explore.frame import (_MAXIMIZE_COLUMNS, ResultFrame, pareto_mask,
                                  stable_topk_indices)
 from repro.explore.resilience import (ChunkError, ChunkTask, ResiliencePolicy,
-                                      Rung, SweepJournal,
+                                      Rung, SweepJournal, SweepKilled,
                                       arch_accs_fingerprint,
                                       reducers_fingerprint, space_fingerprint,
                                       sweep_key)
@@ -88,9 +93,9 @@ DISPATCH_AHEAD = 2
 def default_workers(backend=None) -> int:
   """Thread-pool width: one per core up to 8 for the numpy formulas
   (they release the GIL); 1 for a ``jit=True`` backend — its chunks are
-  dispatched asynchronously with a ``DISPATCH_AHEAD`` in-flight window
-  (and span every visible device via shard_map), so the single
-  submitting thread still overlaps host and device work."""
+  dispatched asynchronously with a ``DISPATCH_AHEAD`` in-flight window,
+  so the single submitting thread still overlaps host and device work
+  (several devices are used through a ``DevicePool``)."""
   if backend is not None and getattr(backend, "jit", False):
     return 1
   return max(1, min(8, os.cpu_count() or 1))
@@ -500,6 +505,125 @@ def fold_chunk(reducers: Dict[str, Reducer], counters: Dict[str, int],
       r.fold(frame, indices)
 
 
+class SweepProgress:
+  """One sweep's progress: its reducers and counters, the chunks already
+  folded, and the journal they checkpoint to.  The one owner of resume,
+  fold, checkpoint and run stats for every engine that drains chunks:
+  :func:`run_stream`, :func:`repro.explore.fleet.run_fleet` and the
+  exploration service.
+
+  ``journal`` is a :class:`SweepJournal` (or its directory) or None.  On
+  construction, its best record under ``key`` (``load_state``) restores
+  the reducers and counters, and the chunks it holds count as resumed.
+  A checkpoint goes through ``write`` — ``journal.record``, the atomic
+  snapshot, unless the caller passes another (the service passes
+  ``journal.append``).  ``policy`` supplies the retry and demotion
+  counts this run adds to the journaled ones, and the watchdog and
+  breaker meta.
+  """
+
+  def __init__(self, reducers: Dict[str, Reducer],
+               policy: Optional[ResiliencePolicy] = None, journal=None,
+               key: str = "", checkpoint_every: int = 1,
+               write: Optional[Callable[[str, Dict[str, object]], None]]
+               = None):
+    self.reducers = reducers
+    self.policy = policy
+    self.counters = new_counters()
+    self.done: set = set()
+    self.key = key
+    self.checkpoint_every = max(int(checkpoint_every), 1)
+    self._since_ckpt = 0
+    self._write = None
+    if journal is not None:
+      if not isinstance(journal, SweepJournal):
+        journal = SweepJournal(journal)
+      self._write = write if write is not None else journal.record
+      state = journal.load_state(key)
+      if state is not None:
+        self.done = set(state["done"])
+        for name, r in reducers.items():
+          r.restore(state["reducers"][name])
+        self.counters.update(state["counters"])
+    self.n_resumed = len(self.done)
+    self._base = (self.counters["n_retries"], self.counters["n_demotions"])
+
+  def todo(self, tasks: Iterable[Task]) -> Iterator[Tuple[int, Task]]:
+    """(global chunk index, task) pairs, skipping already-folded chunks
+    before they are materialized or dispatched."""
+    for i, t in enumerate(tasks):
+      index = getattr(t, "index", i)
+      if index not in self.done:
+        yield index, t
+
+  def fold(self, index: int, result) -> None:
+    """Fold one completed chunk (see :func:`fold_chunk`), mark it done
+    and checkpoint at the cadence.  A kill flushes the journal and
+    propagates as it is; any other failure goes through :meth:`fail`."""
+    try:
+      with spans.at(index):
+        fold_chunk(self.reducers, self.counters, result)
+    except SweepKilled:
+      self.checkpoint(force=True)
+      raise
+    except Exception as e:
+      self.fail(index, e)
+    self.done.add(index)
+    self.checkpoint()
+
+  def fail(self, index: int, exc: Exception) -> NoReturn:
+    """Flush the journal, then surface the failing chunk's global index
+    (a bare re-raise would lose it): a :class:`ChunkError` as it is,
+    anything else wrapped in one."""
+    self.checkpoint(force=True)
+    if isinstance(exc, ChunkError):
+      raise exc
+    raise ChunkError(index, f"{type(exc).__name__}: {exc}") from exc
+
+  def checkpoint(self, force: bool = False) -> None:
+    """Journal the reducers, counters and done set every
+    ``checkpoint_every`` calls, or now with ``force``."""
+    if self._write is None:
+      return
+    self._since_ckpt += 1
+    if not force and self._since_ckpt < self.checkpoint_every:
+      return
+    self.counters["n_retries"], self.counters["n_demotions"] = self.totals()
+    self._write(self.key, {
+        "done": set(self.done),
+        "reducers": {n: r.snapshot() for n, r in self.reducers.items()},
+        "counters": dict(self.counters)})
+    self._since_ckpt = 0
+
+  def totals(self) -> Tuple[int, int]:
+    """Retries and demotions: the journaled ones plus this run's."""
+    r, d = self._base
+    if self.policy is not None:
+      r, d = r + self.policy.n_retries, d + self.policy.n_demotions
+    return r, d
+
+  def result(self, seconds: float, workers: float, **extra) -> StreamResult:
+    """The sweep's :class:`StreamResult`: every reducer's result and the
+    run stats, then ``extra`` (the engine's own meta keys)."""
+    c = self.counters
+    n_retries, n_demotions = self.totals()
+    meta = {"seconds": seconds, "workers": float(workers),
+            "n_chunks": float(c["n_chunks"]),
+            "rows_transferred": float(c["n_transferred"]),
+            "n_retries": float(n_retries),
+            "n_demotions": float(n_demotions),
+            "n_resumed_chunks": float(self.n_resumed),
+            "n_overflows": float(c["n_overflows"])}
+    if self.policy is not None:
+      meta["n_leaked_watchdogs"] = float(self.policy.watchdogs.n_live())
+      if self.policy.breaker is not None:
+        meta.update(self.policy.breaker.meta())
+    meta.update(extra)
+    return StreamResult(
+        results={name: r.result() for name, r in self.reducers.items()},
+        n_rows=c["n_rows"], seconds=seconds, meta=meta)
+
+
 # ROB002: every wait in explore/ must carry a bounded timeout (the
 # watchdog idiom) — the pool waits below re-arm in a loop, so a slow
 # chunk never wedges the submitting thread invisibly
@@ -574,93 +698,28 @@ def _drain(tasks, reducers, recorder, workers, dispatch_ahead, policy,
            resume_from, journal_key, checkpoint_every) -> StreamResult:
   """The body of :func:`run_stream` on one device, inside its span."""
   t0 = time.perf_counter()
-  journal = None
-  done_chunks: set = set()
-  counters = new_counters()
-  n_resumed = 0
-  if resume_from is not None:
-    journal = resume_from if isinstance(resume_from, SweepJournal) \
-        else SweepJournal(resume_from)
-    state = journal.load_state(journal_key)
-    if state is not None:
-      done_chunks = set(state["done"])
-      for name, r in reducers.items():
-        r.restore(state["reducers"][name])
-      counters.update(state["counters"])
-      n_resumed = len(done_chunks)
-  base_retries = counters["n_retries"]
-  base_demotions = counters["n_demotions"]
-  since_ckpt = 0
-
-  def totals() -> Tuple[int, int]:
-    extra_r = policy.n_retries if policy is not None else 0
-    extra_d = policy.n_demotions if policy is not None else 0
-    return base_retries + extra_r, base_demotions + extra_d
-
-  def checkpoint(force: bool = False) -> None:
-    nonlocal since_ckpt
-    if journal is None:
-      return
-    since_ckpt += 1
-    if not force and since_ckpt < max(int(checkpoint_every), 1):
-      return
-    counters["n_retries"], counters["n_demotions"] = totals()
-    journal.record(journal_key, {
-        "done": set(done_chunks),
-        "reducers": {name: r.snapshot() for name, r in reducers.items()},
-        "counters": dict(counters)})
-    since_ckpt = 0
-
-  def execute(task):
-    if policy is not None:
-      return policy.execute(task)
-    return task()
-
-  def fail(index, exc):
-    """Flush the journal, then surface the failing chunk's global
-    index (a bare re-raise would lose it)."""
-    checkpoint(force=True)
-    if isinstance(exc, ChunkError):
-      raise exc
-    raise ChunkError(index, f"{type(exc).__name__}: {exc}") from exc
-
-  def finish(index, result) -> None:
-    try:
-      with spans.at(index):
-        fold_chunk(reducers, counters, result)
-    except Exception as e:
-      fail(index, e)
-    done_chunks.add(index)
-    checkpoint()
+  progress = SweepProgress(reducers, policy, resume_from, journal_key,
+                           checkpoint_every)
 
   def execute_at(index, task):
     with spans.at(index):
-      return execute(task)
-
-  def indexed(ts) -> Iterator[Tuple[int, Task]]:
-    """(global chunk index, task) pairs, skipping already-folded chunks
-    before they are materialized or dispatched."""
-    for i, t in enumerate(ts):
-      index = getattr(t, "index", i)
-      if index in done_chunks:
-        continue
-      yield index, t
+      return policy.execute(task) if policy is not None else task()
 
   if workers == 1:
     window: "deque" = deque()
-    for index, task in indexed(tasks):
+    for index, task in progress.todo(tasks):
       try:
         res = execute_at(index, task)
       except Exception as e:
-        fail(index, e)
+        progress.fail(index, e)
       if hasattr(res, "resolve"):
         window.append((index, res))
         if len(window) > max(int(dispatch_ahead), 0):
-          finish(*window.popleft())
+          progress.fold(*window.popleft())
       else:
-        finish(index, res)
+        progress.fold(index, res)
     while window:
-      finish(*window.popleft())
+      progress.fold(*window.popleft())
   else:
     with ThreadPoolExecutor(max_workers=workers) as pool:
       pending: "deque" = deque()  # (future, global chunk index), FIFO
@@ -675,11 +734,11 @@ def _drain(tasks, reducers, recorder, workers, dispatch_ahead, policy,
         try:
           res = fut.result()
         except Exception as e:
-          fail(index, e)
-        finish(index, res)
+          progress.fail(index, e)
+        progress.fold(index, res)
 
       try:
-        for index, task in indexed(tasks):
+        for index, task in progress.todo(tasks):
           pending.append((pool.submit(on_worker, index, task), index))
           while len(pending) >= 2 * workers:
             drain_oldest()
@@ -691,23 +750,8 @@ def _drain(tasks, reducers, recorder, workers, dispatch_ahead, policy,
         for fut, _ in pending:
           fut.cancel()
         raise
-  checkpoint(force=True)
-  seconds = time.perf_counter() - t0
-  n_retries, n_demotions = totals()
-  meta = {"seconds": seconds, "workers": float(workers),
-          "n_chunks": float(counters["n_chunks"]),
-          "rows_transferred": float(counters["n_transferred"]),
-          "n_retries": float(n_retries),
-          "n_demotions": float(n_demotions),
-          "n_resumed_chunks": float(n_resumed),
-          "n_overflows": float(counters["n_overflows"])}
-  if policy is not None:
-    meta["n_leaked_watchdogs"] = float(policy.watchdogs.n_live())
-    if policy.breaker is not None:
-      meta.update(policy.breaker.meta())
-  return StreamResult(
-      results={name: r.result() for name, r in reducers.items()},
-      n_rows=counters["n_rows"], seconds=seconds, meta=meta)
+  progress.checkpoint(force=True)
+  return progress.result(time.perf_counter() - t0, workers)
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,9 @@ Covers the PR-8 acceptance matrix:
   * the ``SweepJournal`` round-trips reducer state atomically and
     treats corrupt/mismatched records as a fresh start;
   * killing a streamed co-exploration at *every* chunk boundary and
-    resuming reproduces the uninterrupted reductions bit-identically;
+    resuming reproduces the uninterrupted reductions bit-identically,
+    and a journal written by one engine (``run_stream``, ``run_fleet``,
+    the exploration service) resumes under any other;
   * on a ``jit=True`` backend, injected device faults degrade chunks to
     the numpy rung with unchanged results (exact-codegen parity).
 """
@@ -22,13 +24,15 @@ import pytest
 
 from repro.core.cnn import SEARCH_SPACE, ArchChoice
 from repro.core.workloads import get_network
-from repro.explore import (ChunkError, ChunkTask, DesignSpace,
-                           ExplorationSession, Fault, FaultInjected,
-                           FaultPlan, InjectedHang, ParetoAccumulator,
-                           ResiliencePolicy, RetryPolicy, Rung,
-                           StatsAccumulator, SweepJournal, SweepKilled,
-                           TopKAccumulator, VectorOracleBackend, sweep_key)
+from repro.explore import (ChunkError, ChunkTask, DesignSpace, DevicePool,
+                           ExplorationService, ExplorationSession, Fault,
+                           FaultInjected, FaultPlan, InjectedHang,
+                           ParetoAccumulator, ResiliencePolicy, ResultStore,
+                           RetryPolicy, Rung, StatsAccumulator, SweepJournal,
+                           SweepKilled, TopKAccumulator, VectorOracleBackend,
+                           stream_co_explore, sweep_key)
 from repro.explore.resilience import ChunkTimeout
+from repro.explore.streaming import co_explore_sweep_key
 from repro.train.fault_tolerance import StepFailure, retrying
 
 METRICS = ("latency_s", "power_mw", "area_mm2")
@@ -362,6 +366,62 @@ class TestKillAndResume:
     assert_same_results(res, ref)
     assert res.meta["n_retries"] == 2.0
     assert res.meta["n_demotions"] == 0.0
+
+
+# ---------------------------------------------------------------------------
+# one journal, three engines: kill under one, resume under another
+# ---------------------------------------------------------------------------
+
+ENGINES = ("run_stream", "run_fleet", "service")
+CO_KW = dict(n_hw_per_type=10, seed=3, image_size=16, chunk_size=13)
+
+
+def run_engine(engine, arch_accs, store, fault_plan=None):
+  """One streamed co-exploration journaled under ``store``'s journal:
+  ``run_stream`` and ``run_fleet`` (a one-device ``DevicePool``) through
+  ``stream_co_explore``, or a session of an ``ExplorationService`` over
+  the store, which journals under the same sweep key."""
+  backend = VectorOracleBackend(chunk_size=512)
+  if engine == "service":
+    svc = ExplorationService(backend, store=store, retry=no_wait(),
+                             fault_plan=fault_plan)
+    handle = svc.submit_co_explore(DesignSpace(), arch_accs,
+                                   reducers=co_reducers(), **CO_KW)
+    svc.drain()
+    return handle.result()
+  pool = DevicePool(devices=["dev0"]) if engine == "run_fleet" else None
+  return stream_co_explore(
+      backend, DesignSpace(), arch_accs, reducers=co_reducers(),
+      policy=ResiliencePolicy(retry=no_wait(), fault_plan=fault_plan),
+      resume_from=store.journal, pool=pool, **CO_KW)
+
+
+@pytest.mark.parametrize("killed,resumed",
+                         [(k, r) for k in ENGINES for r in ENGINES])
+def test_resume_across_engines(killed, resumed, arch_accs, tmp_path):
+  ref = stream_co_explore(VectorOracleBackend(chunk_size=512),
+                          DesignSpace(), arch_accs, reducers=co_reducers(),
+                          **CO_KW)
+  n_chunks = int(ref.meta["n_chunks"])
+  kill_at = n_chunks // 2
+  plan = FaultPlan([Fault("kill", kill_at, "task")])
+  # run_stream surfaces the kill as the chunk's ChunkError, the fleet and
+  # the service as the SweepKilled itself
+  with pytest.raises((ChunkError, SweepKilled)):
+    run_engine(killed, arch_accs, ResultStore(tmp_path), plan)
+  key = co_explore_sweep_key(DesignSpace(), co_reducers(), arch_accs,
+                             n_hw_per_type=10, seed=3, image_size=16,
+                             method="random", chunk_size=13)
+  journaled = ResultStore(tmp_path).journal.load_state(key)["done"]
+  # every chunk before the kill was folded, but for the one the fleet
+  # still held in its dispatch window
+  in_flight = 1 if killed == "run_fleet" else 0
+  assert set(journaled) == set(range(kill_at - in_flight))
+  res = run_engine(resumed, arch_accs, ResultStore(tmp_path))
+  assert_same_results(res, ref)
+  assert res.meta["n_resumed_chunks"] == float(len(journaled))
+  assert res.meta["n_chunks"] == float(n_chunks)
+  assert res.n_rows == ref.n_rows
 
 
 # ---------------------------------------------------------------------------

@@ -575,3 +575,109 @@ class TestFailureSemantics:
     for key in ("n_retries", "n_demotions", "n_resumed_chunks",
                 "n_overflows"):
       assert res.meta[key] == 0.0, key  # healthy run: all zero, all present
+
+
+class TestSweepProgress:
+  """``SweepProgress``, the one owner of a sweep's resume, folds,
+  checkpoints and run stats for every chunk engine."""
+
+  KEY = "p" * 64
+  META_KEYS = {"seconds", "workers", "n_chunks", "rows_transferred",
+               "n_retries", "n_demotions", "n_resumed_chunks",
+               "n_overflows"}
+
+  @staticmethod
+  def chunk(i, rows=5):
+    frame = random_frame(np.random.RandomState(100 + i), rows)
+    return frame, np.arange(i * rows, (i + 1) * rows, dtype=np.int64)
+
+  @staticmethod
+  def reducers():
+    return {"pareto": ParetoAccumulator(("latency_s", "power_mw")),
+            "stats": StatsAccumulator("power_mw")}
+
+  def progress(self, tmp_path, writes=None, **kw):
+    from repro.explore import SweepJournal
+    from repro.explore.streaming import SweepProgress
+    write = None if writes is None \
+        else (lambda key, state: writes.append(sorted(state["done"])))
+    return SweepProgress(self.reducers(), journal=SweepJournal(tmp_path),
+                         key=self.KEY, write=write, **kw)
+
+  def test_checkpoints_at_the_cadence_and_when_forced(self, tmp_path):
+    writes = []
+    progress = self.progress(tmp_path, writes, checkpoint_every=3)
+    for i in range(7):
+      progress.fold(i, self.chunk(i))
+    assert writes == [[0, 1, 2], [0, 1, 2, 3, 4, 5]]
+    progress.checkpoint(force=True)
+    assert writes[-1] == list(range(7))
+
+  def test_resumes_from_the_record_and_skips_folded_chunks(self, tmp_path):
+    first = self.progress(tmp_path)
+    for i in range(3):
+      first.fold(i, self.chunk(i))
+    again = self.progress(tmp_path)
+    assert again.n_resumed == 3
+    assert again.counters == first.counters
+    tasks = [lambda i=i: self.chunk(i) for i in range(5)]
+    assert [i for i, _ in again.todo(tasks)] == [3, 4]
+    for i in (3, 4):
+      again.fold(i, self.chunk(i))
+    whole = self.progress(tmp_path / "whole")
+    for i in range(5):
+      whole.fold(i, self.chunk(i))
+    got, want = again.result(0.0, 1), whole.result(0.0, 1)
+    for col in ("latency_s", "power_mw", "area_mm2"):
+      assert np.array_equal(getattr(got["pareto"], col),
+                            getattr(want["pareto"], col)), col
+    assert got["stats"] == want["stats"]
+    assert got.meta["n_resumed_chunks"] == 3.0
+    assert got.meta["n_chunks"] == want.meta["n_chunks"] == 5.0
+
+  def test_fail_wraps_with_the_global_index_and_flushes(self, tmp_path):
+    from repro.explore import ChunkError
+    writes = []
+    progress = self.progress(tmp_path, writes, checkpoint_every=100)
+    cause = ValueError("bad chunk")
+    with pytest.raises(ChunkError) as err:
+      progress.fail(4, cause)
+    assert err.value.chunk_index == 4
+    assert err.value.__cause__ is cause
+    assert len(writes) == 1  # forced, whatever the cadence
+    typed = ChunkError(9, "already typed")
+    with pytest.raises(ChunkError) as err:
+      progress.fail(2, typed)
+    assert err.value is typed and err.value.chunk_index == 9
+
+  def test_a_failing_fold_is_not_marked_done(self, tmp_path):
+    from repro.explore import ChunkError, SweepKilled
+
+    class Broken:
+      def __init__(self, exc):
+        self.exc = exc
+
+      def resolve(self):
+        raise self.exc
+
+    writes = []
+    progress = self.progress(tmp_path, writes)
+    with pytest.raises(ChunkError) as err:
+      progress.fold(3, Broken(ValueError("resolve failed")))
+    assert err.value.chunk_index == 3
+    # a kill is never wrapped, and the journal is flushed on its way out
+    with pytest.raises(SweepKilled):
+      progress.fold(5, Broken(SweepKilled("kill -9")))
+    assert writes == [[], []]
+    assert progress.done == set()
+
+  def test_result_meta_keys(self, tmp_path):
+    from repro.explore import ResiliencePolicy
+    from repro.explore.streaming import SweepProgress
+    plain = SweepProgress(self.reducers()).result(1.5, 2)
+    assert set(plain.meta) == self.META_KEYS
+    assert plain.meta["workers"] == 2.0 and plain.meta["seconds"] == 1.5
+    with_policy = SweepProgress(self.reducers(), ResiliencePolicy())
+    res = with_policy.result(1.5, 1, extra_key=7.0)
+    assert set(res.meta) == self.META_KEYS | {"n_leaked_watchdogs",
+                                              "extra_key"}
