@@ -560,18 +560,24 @@ class FusedChunk:
   evidence: ``cap`` rows per in-cap pareto reducer, ``k`` per top-k);
   ``n_overflows`` counts pareto reducers whose survivor count blew the
   plan cap and fell back to the full chunk frame — the first rung of
-  the graceful-degradation story (see repro.explore.resilience)."""
+  the graceful-degradation story (see repro.explore.resilience);
+  ``n_prefetched`` counts the rows whose host copy started at launch
+  (``cap`` per pareto reducer, ``k`` per top-k): ``n_transferred``
+  unless a reducer overflowed."""
   payloads: Dict[str, tuple]
   n_rows: int
   n_transferred: int = 0
   n_overflows: int = 0
+  n_prefetched: int = 0
 
 
 class _PendingBase:
   """A dispatched device chunk.  Construction dispatches the program
-  (jax async); ``resolve()`` blocks on / fetches only what the reducers
-  need.  The streaming engine keeps a small window of these in flight so
-  host chunk materialization overlaps device execution."""
+  (jax async) and, for a fused chunk, starts the host copies of what the
+  reducers need; ``resolve()`` blocks on and reads only those.  The
+  streaming engine keeps a small window of these in flight so host
+  chunk materialization overlaps device execution, and the copies land
+  while the window's later chunks are produced."""
 
   _buffers = None  # output pytree backing is_ready, when tracked
 
@@ -608,6 +614,10 @@ class PendingFused(_PendingBase):
 
   ``full_frame`` builds the chunk's ordinary full frame from the device
   metric arrays — used by overflowing pareto reducers only.
+  Construction starts the host copy of every reducer output (the
+  cap-sized survivors, top-k rows, counts, stats and histograms), so
+  ``resolve`` pays no device round trip per array; the full arrays are
+  fetched only on an overflow.
   """
 
   def __init__(self, outputs, plan: DevicePlan, table: ConfigTable,
@@ -626,6 +636,12 @@ class PendingFused(_PendingBase):
     self.accs = accs
     self.arch_lookup = tuple(arch_lookup)
     self._joint = accs is not None
+    self.n_prefetched = sum(self._reduced[name]["idx"].shape[0]
+                            for name, spec in plan
+                            if isinstance(spec, (ParetoSpec, TopKSpec)))
+    import jax
+    for leaf in jax.tree_util.tree_leaves(self._reduced):
+      leaf.copy_to_host_async()
 
   # -- frame builders -------------------------------------------------------
 
@@ -660,9 +676,10 @@ class PendingFused(_PendingBase):
     """Wait for the program, fetch what every reducer needs (each in-cap
     pareto reducer's survivors whole, at the plan's fixed ``cap``), cut
     those survivors to their count on the host, and build each payload:
-    one span per phase, around the loop over reducers.  Cutting on the
-    host rather than slicing device arrays keeps every survivor count
-    from compiling a slice program of its own."""
+    one span per phase, around the loop over reducers.  The reads find
+    the host copies construction started, made or in flight.  Cutting
+    on the host rather than slicing device arrays keeps every survivor
+    count from compiling a slice program of its own."""
     with spans.span("resolve") as resolving:
       with spans.span("wait"):  # the first blocking read
         counts = {name: int(self._reduced[name]["count"])
@@ -705,4 +722,5 @@ class PendingFused(_PendingBase):
           payloads[name] = ("rows", self._mini_frame(local, rows),
                             self.indices[local])
       return FusedChunk(payloads=payloads, n_rows=len(self.indices),
-                        n_transferred=transferred, n_overflows=overflows)
+                        n_transferred=transferred, n_overflows=overflows,
+                        n_prefetched=self.n_prefetched)

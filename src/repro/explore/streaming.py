@@ -478,7 +478,8 @@ class StreamResult:
 def new_counters() -> Dict[str, int]:
   """A fresh run-stats dict in the shape the journal checkpoints."""
   return {"n_rows": 0, "n_chunks": 0, "n_transferred": 0,
-          "n_overflows": 0, "n_retries": 0, "n_demotions": 0}
+          "n_prefetched": 0, "n_overflows": 0, "n_retries": 0,
+          "n_demotions": 0}
 
 
 def fold_chunk(reducers: Dict[str, Reducer], counters: Dict[str, int],
@@ -494,6 +495,7 @@ def fold_chunk(reducers: Dict[str, Reducer], counters: Dict[str, int],
     if payloads is not None:  # a device FusedChunk (duck-typed: keeps
       counters["n_rows"] += result.n_rows  # numpy path device-import-free
       counters["n_transferred"] += result.n_transferred
+      counters["n_prefetched"] += result.n_prefetched
       counters["n_overflows"] += getattr(result, "n_overflows", 0)
       for name, payload in payloads.items():
         reducers[name].fold_payload(payload)
@@ -610,6 +612,7 @@ class SweepProgress:
     meta = {"seconds": seconds, "workers": float(workers),
             "n_chunks": float(c["n_chunks"]),
             "rows_transferred": float(c["n_transferred"]),
+            "rows_prefetched": float(c["n_prefetched"]),
             "n_retries": float(n_retries),
             "n_demotions": float(n_demotions),
             "n_resumed_chunks": float(self.n_resumed),

@@ -18,8 +18,9 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from repro.core.cnn import SEARCH_SPACE, ArchChoice
-from repro.core.dataflow import LayerStack
+from repro.core.dataflow import GemmLayer, LayerStack
 from repro.core.pe import PAPER_PE_TYPES
+from repro.core.supernet import arch_to_layers
 from repro.core.workloads import get_network
 from repro.explore import (DesignSpace, ExplorationSession,
                            VectorOracleBackend)
@@ -341,6 +342,154 @@ class TestFusedReducers:
                          reducers={"frame": CollectAccumulator()},
                          chunk_size=37)
     _assert_frames_equal(dev["frame"], host["frame"], "collect")
+
+
+# a small GEMM network: sweeps of it run the table program
+GEMMS = (GemmLayer("dense", 128, 2048, 3072),
+         GemmLayer("heads", 128, 128, 512, groups=16, count=27),
+         GemmLayer("scores", 16, 576, 32768, groups=128, operand="cache",
+                   count=27),
+         GemmLayer("expert", 3, 2048, 1408, count=268))
+
+
+def _sweep(kind, backend, layers, arch_accs, space, reducers):
+  """One streamed sweep through the conv, table or joint program (or,
+  on a numpy backend, the unfused numpy rung)."""
+  if kind == "joint":
+    return stream_co_explore(backend, space, arch_accs, n_hw_per_type=13,
+                             seed=3, image_size=16, reducers=reducers,
+                             chunk_size=41)
+  return stream_explore(backend, space, layers if kind == "conv" else GEMMS,
+                        n_per_type=40, seed=9, reducers=reducers,
+                        chunk_size=53)
+
+
+class _Leaf:
+  """A device output that logs the host copies asked of it and the reads
+  made of it, and forwards everything else to the array."""
+
+  def __init__(self, array, log):
+    self.array, self.log = array, log
+
+  def __getattr__(self, name):
+    return getattr(self.array, name)
+
+  def copy_to_host_async(self):
+    self.log["asked"].append(self)
+    self.array.copy_to_host_async()
+
+  def _read(self):
+    self.log["read"].append(self)
+    return self.array
+
+  def __array__(self, dtype=None, copy=None):
+    return np.asarray(self._read(), dtype)
+
+  def __int__(self):
+    return int(self._read())
+
+  def __float__(self):
+    return float(self._read())
+
+
+class TestSurvivorPrefetch:
+  """A fused chunk starts the host copy of every reducer output at
+  launch, and ``resolve`` reads those copies: the same bytes, so the
+  same fronts, and ``rows_prefetched`` beside ``rows_transferred``."""
+
+  @pytest.mark.parametrize("kind", ["plain", "joint"])
+  def test_prefetched_rows_equal_transferred(self, kind, layers, arch_accs,
+                                             space):
+    reducers = _reducers() if kind == "plain" else _joint_reducers()
+    res = _sweep("conv" if kind == "plain" else kind,
+                 VectorOracleBackend(jit=True), layers, arch_accs, space,
+                 reducers)
+    assert res.meta["n_overflows"] == 0
+    assert res.meta["rows_prefetched"] == res.meta["rows_transferred"] > 0
+
+  def test_overflow_transfers_more_than_prefetched(self, layers, space):
+    """A cap below every chunk's front: each chunk's rows come over on
+    demand besides its prefetched ``cap`` rows, and the front is still
+    the numpy path's."""
+    from repro.explore.device import build_plan
+    backend = VectorOracleBackend(jit=True)
+    cols = ("latency_s", "power_mw", "area_mm2")
+    tbl = space.sample_table(60, seed=6)
+    reducers = {"pareto": ParetoAccumulator(cols)}
+    plan = build_plan(reducers, joint=False, cap=1)
+    bounds = [(0, 80), (80, 160), (160, len(tbl))]
+    tasks = [(lambda lo=lo, hi=hi: backend.fused_eval_pending(
+        tbl.select(slice(lo, hi)), layers, "net", plan,
+        np.arange(lo, hi, dtype=np.int64))) for lo, hi in bounds]
+    res = run_stream(iter(tasks), reducers)
+    assert res.meta["n_overflows"] == len(bounds)
+    assert res.meta["rows_prefetched"] == len(bounds) * plan.cap
+    assert res.meta["rows_transferred"] == len(tbl)
+    base = VectorOracleBackend().evaluate_table(tbl, layers)
+    mask = base.pareto(cols)
+    _assert_frames_equal(res["pareto"], base.select(mask), "overflow")
+    assert np.array_equal(reducers["pareto"].indices, np.flatnonzero(mask))
+
+  @pytest.mark.parametrize("kind", ["conv", "table", "joint"])
+  def test_fronts_match_the_numpy_rung(self, kind, layers, arch_accs, space):
+    """Fronts and top-k, ids and values, bit-identical to the unfused
+    numpy rung through each of the three fused programs."""
+    make = _joint_reducers if kind == "joint" else _reducers
+    got, want = make(), make()
+    dev = _sweep(kind, VectorOracleBackend(jit=True), layers, arch_accs,
+                 space, got)
+    host = _sweep(kind, VectorOracleBackend(), layers, arch_accs, space, want)
+    assert dev.meta["n_chunks"] > 1
+    assert dev.meta["rows_prefetched"] > 0 == host.meta["rows_prefetched"]
+    for name in ("pareto", "top"):
+      _assert_frames_equal(dev[name], host[name], (kind, name))
+      assert np.array_equal(got[name].indices, want[name].indices), name
+
+  @pytest.mark.parametrize("kind", ["plain", "joint"])
+  def test_resolve_reads_only_prefetched_arrays(self, kind, layers,
+                                                arch_accs, space):
+    """Every array ``resolve`` reads on the no-overflow path had its
+    host copy asked for at construction, and the chunk's full metric
+    arrays had none."""
+    from repro.explore import device as device_lib
+    from repro.explore.device import build_plan
+    backend = VectorOracleBackend(jit=True)
+    hw = space.sample_table(8, seed=2)
+    if kind == "plain":
+      plan = build_plan(_reducers(), joint=False)
+      idx = np.arange(len(hw), dtype=np.int64)
+      pend = backend.fused_eval_pending(hw, layers, "net", plan, idx)
+      kw = {}
+    else:
+      plan = build_plan(_joint_reducers(), joint=True)
+      archs = tuple(a for a, _ in arch_accs)
+      accs = np.asarray([acc for _, acc in arch_accs], np.float64)
+      stack = LayerStack.from_layer_lists(
+          [arch_to_layers(a, image_size=16) for a in archs])
+      idx = np.arange(len(hw) * len(archs), dtype=np.int64)
+      pend = backend.fused_co_eval_pending(hw, stack, "net", plan, idx, 0,
+                                           accs, archs)
+      kw = dict(n_hw=len(hw), accs=accs, arch_lookup=archs)
+    log = {"asked": [], "read": []}
+    wrapped = jax.tree_util.tree_map(lambda a: _Leaf(a, log), pend._buffers)
+    chunk = device_lib.PendingFused(wrapped, plan, hw, idx, "net",
+                                    **kw).resolve()
+    full, reduced = wrapped
+    assert chunk.n_overflows == 0 and log["read"]
+    asked = {id(leaf) for leaf in log["asked"]}
+    assert asked == {id(leaf) for leaf in jax.tree_util.tree_leaves(reduced)}
+    assert {id(leaf) for leaf in log["read"]} <= asked
+    assert not asked & {id(leaf) for leaf in full}
+    want = pend.resolve()
+    assert chunk.n_transferred == want.n_transferred
+    for name, spec in plan:
+      if isinstance(spec, (device_lib.ParetoSpec, device_lib.TopKSpec)):
+        _, frame, ids = chunk.payloads[name]
+        _, want_frame, want_ids = want.payloads[name]
+        _assert_frames_equal(frame, want_frame, name)
+        assert np.array_equal(ids, want_ids), name
+      else:
+        assert str(chunk.payloads[name]) == str(want.payloads[name]), name
 
 
 class TestParetoFrontKernel:

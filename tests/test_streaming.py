@@ -583,8 +583,8 @@ class TestSweepProgress:
 
   KEY = "p" * 64
   META_KEYS = {"seconds", "workers", "n_chunks", "rows_transferred",
-               "n_retries", "n_demotions", "n_resumed_chunks",
-               "n_overflows"}
+               "rows_prefetched", "n_retries", "n_demotions",
+               "n_resumed_chunks", "n_overflows"}
 
   @staticmethod
   def chunk(i, rows=5):
