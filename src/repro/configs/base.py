@@ -56,6 +56,12 @@ class ModelConfig:
   q_lora_rank: int = 0                 # 0 => queries not compressed
   qk_rope_head_dim: int = 0
   v_head_dim: int = 0
+  # DeepSeek Sparse Attention: a lightning indexer of index_n_heads heads
+  # of index_head_dim scores every cached position, and the attention
+  # reads only the index_topk best
+  index_topk: int = 0                  # 0 => no indexer, dense attention
+  index_n_heads: int = 0
+  index_head_dim: int = 0
 
   # hybrid / ssm
   attn_period: int = 0                 # jamba: 1 attn per this many layers

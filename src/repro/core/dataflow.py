@@ -136,13 +136,16 @@ class GemmLayer:
   DRAM words: the left operand M K once if it fits half the global
   buffer at ``act_bits``, else once per N tile; the right operand K N
   once if it fits a quarter of the buffer at its own bits, else once per
-  column fold; the output M N once at ``psum_bits``.  A ``cache``
-  operand moves at ``act_bits`` through the scratchpads, the buffer and
-  DRAM (``weight_bits`` for a weight), and its fit test is per group, so
-  a batch's caches never share the buffer.  The stall past 85% overlap
-  and every access count follow the conv model.  One instance's cycles,
-  accesses and MACs are ``groups`` times one group's; a network counts
-  its latency, energy and utilization ``count`` times.
+  column fold; the output M N once at ``psum_bits``.  The right operand
+  moves at its own width through the scratchpads, the buffer and DRAM:
+  ``bits`` where the entry stores one (say, an 8-bit key cache), else
+  ``act_bits`` for a ``cache`` and ``weight_bits`` for a weight.  That
+  width decides the quarter-buffer fit test, the right operand's DRAM
+  bits and its scratchpad, buffer and DRAM energy.  A cache's fit test
+  is per group, so a batch's caches never share the buffer.  The stall
+  past 85% overlap and every access count follow the conv model.  One
+  instance's cycles, accesses and MACs are ``groups`` times one group's;
+  a network counts its latency, energy and utilization ``count`` times.
   """
   name: str
   M: int
@@ -151,6 +154,7 @@ class GemmLayer:
   groups: int = 1
   operand: str = "weight"
   count: int = 1
+  bits: Optional[int] = None  # the right operand's stored width
 
   def __post_init__(self):
     if self.operand not in OPERANDS:
@@ -160,11 +164,14 @@ class GemmLayer:
       if int(getattr(self, field)) < 1:
         raise ValueError(f"{field} must be at least 1, got "
                          f"{getattr(self, field)}")
+    # widths 0 and 1 are the table's codes for the PE type's widths
+    if self.bits is not None and int(self.bits) < 2:
+      raise ValueError(f"bits must be at least 2, got {self.bits}")
 
   @property
-  def shape(self) -> Tuple[int, int, int, int, str]:
+  def shape(self) -> Tuple[int, int, int, int, str, Optional[int]]:
     """What identical layers share, whatever their names and counts."""
-    return (self.M, self.K, self.N, self.groups, self.operand)
+    return (self.M, self.K, self.N, self.groups, self.operand, self.bits)
 
   @property
   def macs(self) -> int:
@@ -225,6 +232,8 @@ class LayerStats:
 
 def _right_bits(pe: pe_lib.PEType, layer) -> int:
   """Bits a word of the right operand (weights, or a cache) moves at."""
+  if getattr(layer, "bits", None) is not None:
+    return layer.bits
   if getattr(layer, "operand", "weight") == "cache":
     return pe.act_bits
   return pe.weight_bits
@@ -485,8 +494,8 @@ def _layer_feats(layer: ConvLayer) -> Dict[str, float]:
   floats (one ConvLayer) — :class:`LayerStack` supplies the same keys as
   broadcastable ``(n_archs, 1)`` arrays.  A :class:`GemmLayer` adds the
   GEMM-only keys ``E_row`` (an output row's width), ``groups`` and
-  ``cache`` (1.0 for a cache operand); a conv layer has none of them, so
-  its formulas trace exactly as before."""
+  ``cache``, the right operand's width code (:func:`width_code`); a conv
+  layer has none of them, so its formulas trace exactly as before."""
   if isinstance(layer, GemmLayer):
     return {
         "E": float(layer.M), "E_row": 1.0, "K": 1.0,
@@ -496,7 +505,7 @@ def _layer_feats(layer: ConvLayer) -> Dict[str, float]:
         "weight_words": float(layer.K * layer.N),
         "of_words": float(layer.M * layer.N),
         "groups": float(layer.groups),
-        "cache": 1.0 if layer.operand == "cache" else 0.0,
+        "cache": width_code(layer),
     }
   return {
       "E": float(max(layer.out_dim, 1)),
@@ -508,13 +517,24 @@ def _layer_feats(layer: ConvLayer) -> Dict[str, float]:
   }
 
 
+def width_code(layer: GemmLayer) -> float:
+  """The ``cache`` feature of a GEMM entry, its right operand's width:
+  0 for the PE type's ``weight_bits``, 1 for its ``act_bits`` (a cache
+  without a width of its own), k > 1 for k bits stored."""
+  if layer.bits is not None:
+    return float(layer.bits)
+  return 1.0 if layer.operand == "cache" else 0.0
+
+
 def _right_bits_feats(c, f, xp):
-  """Bits of the right operand per (layer, design point): weight bits,
-  or activation bits for a cache operand.  Layers without a ``cache``
-  feature (every conv layer) take ``weight_bits`` at trace time."""
+  """Bits of the right operand per (layer, design point), decoded from
+  the ``cache`` feature (:func:`width_code`).  Layers without one (every
+  conv layer) take ``weight_bits`` at trace time."""
   if "cache" not in f:
     return c["weight_bits"]
-  return xp.where(f["cache"] > 0.0, c["act_bits"], c["weight_bits"])
+  code = f["cache"]
+  return xp.where(code > 1.0, code,
+                  xp.where(code > 0.0, c["act_bits"], c["weight_bits"]))
 
 
 def _simulate_layer_feats(c, f, clock_mhz, xp) -> LayerStatsBatch:
@@ -684,7 +704,8 @@ def simulate_network_batch(table, layers: Sequence[ConvLayer],
 # ---------------------------------------------------------------------------
 
 # the feature columns of a layer table, the keys of _layer_feats for a
-# GemmLayer (a conv row takes E_row = E, groups = 1, cache = 0)
+# GemmLayer (a conv row takes E_row = E, groups = 1, cache = 0); the
+# right operand's width rides in ``cache``, so it adds no column
 TABLE_FEATURES = ("E", "E_row", "K", "C", "F", "macs", "ifmap_words",
                   "weight_words", "of_words", "groups", "cache")
 

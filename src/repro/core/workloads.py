@@ -191,22 +191,43 @@ def _regime(shape):
   return shape
 
 
+# the lightning indexer's keys are cached in FP8 (DeepSeek-V3.2-Exp)
+INDEX_KEY_BITS = 8
+
+
 def from_config(cfg, shape, routing: Optional[Sequence] = None
                 ) -> List[GemmLayer]:
   """One step of ``cfg`` in the regime ``shape`` as merged GEMM entries,
-  in order of first appearance: identical layers (same M, K, N, groups
-  and operand) are one entry whose ``count`` says how many.
+  in order of first appearance: identical layers (same M, K, N, groups,
+  operand and stored width) are one entry whose ``count`` says how many.
 
   Lowered: ``decode`` (``global_batch`` sequences, one new token each,
   against ``seq_len`` cached positions) of a model with multi-head latent
-  attention (``kv_lora_rank``, no query compression) and SwiGLU MLPs,
-  dense or DeepSeekMoE.  B tokens, L cached positions, H heads, per layer:
+  attention (``kv_lora_rank``), its queries compressed or not, with or
+  without DeepSeek Sparse Attention's lightning indexer, and SwiGLU MLPs,
+  dense or DeepSeekMoE.  B tokens, L cached positions, H heads, per
+  layer:
 
-    q_proj     B x d -> H (dn + dr)
+    q_proj     B x d -> H (dn + dr)         (q_lora_rank 0), or
+    q_a        B x d -> q_lora              (compressed queries)
+    q_b        B x q_lora -> H (dn + dr)
     q_absorb   groups H: B x dn -> r            (W_UK absorbed)
     kv_a_proj  B x d -> r + dr                  (written to the cache)
-    scores     groups B: H x (r + dr) -> L      cache operand
-    av         groups B: H x L -> r             cache operand
+
+  then, with an indexer (``index_topk`` > 0; Hi = ``index_n_heads``
+  heads of di = ``index_head_dim``), whose scores pick the k =
+  min(``index_topk``, L) positions the attention reads:
+
+    index.q       B x q_lora -> Hi di           (from the query latent)
+    index.k       B x d -> di                   (written to its key cache)
+    index.w       B x d -> Hi                   (head weights)
+    index.scores  groups B: Hi x di -> L        key cache, INDEX_KEY_BITS
+    index.reduce  groups B: 1 x Hi -> L         cache: ReLU'd scores
+
+  and without one k = L; last the attention itself:
+
+    scores     groups B: H x (r + dr) -> k      cache operand
+    av         groups B: H x k -> r             cache operand
     v_absorb   groups H: B x r -> dv            (W_UV absorbed)
     o_proj     B x H dv -> d
 
@@ -218,10 +239,12 @@ def from_config(cfg, shape, routing: Optional[Sequence] = None
   vocab.  ``routing`` gives the routed tokens per expert, one sequence
   per MoE layer; None draws them by :func:`expert_token_counts`.
 
-  Left out: softmax and the norms (elementwise work outside the MAC
-  array; the scores still go to DRAM and come back as the av product's
-  input), the rope product, and the embedding lookup.  Any other regime
-  or mechanism raises ``NotImplementedError``.
+  Left out: softmax, the norms and the top-k selection (no MAC work
+  here; the scores still go to DRAM and come back as the next product's
+  input), the rope product, the Hadamard rotation and FP8 quantization
+  of the indexer's inputs, the embedding lookup, and multi-token
+  prediction modules, which a plain decode step does not run.  Any other
+  regime or mechanism raises ``NotImplementedError``.
   """
   from repro.explore import spans  # deferred: explore imports this module
   with spans.span("lower"):
@@ -232,8 +255,8 @@ def from_config(cfg, shape, routing: Optional[Sequence] = None
     why = []
     if cfg.kv_lora_rank <= 0:
       why.append("attention other than multi-head latent attention")
-    if cfg.q_lora_rank > 0:
-      why.append("compressed queries (q_lora_rank)")
+    if cfg.index_topk > 0 and cfg.q_lora_rank <= 0:
+      why.append("an indexer without compressed queries")
     if cfg.sliding_window:
       why.append("windowed attention")
     if cfg.family not in ("dense", "moe"):
@@ -249,6 +272,8 @@ def from_config(cfg, shape, routing: Optional[Sequence] = None
     d, h = cfg.d_model, cfg.n_heads
     dn, dr, dv, r = (cfg.head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
                      cfg.kv_lora_rank)
+    q_lora, hi, di = cfg.q_lora_rank, cfg.index_n_heads, cfg.index_head_dim
+    read = min(cfg.index_topk, ctx) if cfg.index_topk > 0 else ctx
     n_moe = cfg.n_layers - cfg.first_dense_layers if cfg.n_experts else 0
     if n_moe:
       counts = expert_token_counts(cfg, b) if routing is None \
@@ -260,9 +285,9 @@ def from_config(cfg, shape, routing: Optional[Sequence] = None
     entries: List[GemmLayer] = []
     where: Dict[tuple, int] = {}
 
-    def add(name, m, k, n, groups=1, operand="weight", count=1):
+    def add(name, m, k, n, groups=1, operand="weight", count=1, bits=None):
       layer = GemmLayer(name, int(m), int(k), int(n), int(groups), operand,
-                        count)
+                        count, bits)
       i = where.setdefault(layer.shape, len(entries))
       if i == len(entries):
         entries.append(layer)
@@ -275,11 +300,22 @@ def from_config(cfg, shape, routing: Optional[Sequence] = None
       add(f"{prefix}.down", tokens, width, d)
 
     for i in range(cfg.n_layers):
-      add("attn.q_proj", b, d, h * (dn + dr))
+      if q_lora > 0:
+        add("attn.q_a", b, d, q_lora)
+        add("attn.q_b", b, q_lora, h * (dn + dr))
+      else:
+        add("attn.q_proj", b, d, h * (dn + dr))
       add("attn.q_absorb", b, dn, r, groups=h)
       add("attn.kv_a_proj", b, d, r + dr)
-      add("attn.scores", h, r + dr, ctx, groups=b, operand="cache")
-      add("attn.av", h, ctx, r, groups=b, operand="cache")
+      if cfg.index_topk > 0:
+        add("index.q", b, q_lora, hi * di)
+        add("index.k", b, d, di)
+        add("index.w", b, d, hi)
+        add("index.scores", hi, di, ctx, groups=b, operand="cache",
+            bits=INDEX_KEY_BITS)
+        add("index.reduce", 1, hi, ctx, groups=b, operand="cache")
+      add("attn.scores", h, r + dr, read, groups=b, operand="cache")
+      add("attn.av", h, read, r, groups=b, operand="cache")
       add("attn.v_absorb", b, r, dv, groups=h)
       add("attn.o_proj", b, h * dv, d)
       if i < cfg.n_layers - n_moe:

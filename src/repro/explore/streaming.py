@@ -995,11 +995,18 @@ def stream_explore(backend, space: DesignSpace, layers, network: str = "net",
 
 def layer_meta(layers) -> Dict[str, float]:
   """What a plain sweep evaluated per point: ``n_layer_shapes``, the
-  layer entries (each a distinct shape once identical layers merge),
-  and ``n_layer_slots``, the layers they stand for (the sum of counts)."""
+  layer entries (each a distinct shape once identical layers merge);
+  ``n_layer_slots``, the layers they stand for (the sum of counts);
+  ``n_cache_shapes``, the entries whose right operand is a cache; and
+  ``n_narrow_shapes``, those whose right operand has a stored width of
+  its own."""
   from repro.core.dataflow import layer_count
   return {"n_layer_shapes": float(len(layers)),
-          "n_layer_slots": float(sum(layer_count(l) for l in layers))}
+          "n_layer_slots": float(sum(layer_count(l) for l in layers)),
+          "n_cache_shapes": float(sum(
+              getattr(l, "operand", "weight") == "cache" for l in layers)),
+          "n_narrow_shapes": float(sum(
+              getattr(l, "bits", None) is not None for l in layers))}
 
 
 def stream_co_explore(backend, space: DesignSpace, arch_accs,

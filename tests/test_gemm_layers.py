@@ -2,13 +2,15 @@
 
   * ``GemmLayer``: the scalar model and the numpy batch formulas agree bit
     for bit on every ``LayerStats`` field and on the energy, for groups,
-    cache operands, counts and both branches of each fit test;
-  * ``from_config``: DeepSeek-V2-Lite's decode step lowers to the MACs and
-    cache words counted from its config by hand, with routing that sends
-    every token to ``n_experts_active`` experts;
+    cache operands, stored widths, counts and both branches of each fit
+    test; entries without a stored width keep every number they had;
+  * ``from_config``: DeepSeek-V2-Lite's and DeepSeek-V3.2's decode steps
+    lower to the MACs and cache words counted from their configs by hand,
+    with routing that sends every token to ``n_experts_active`` experts;
   * the table program (numpy and ``jit=True`` on the CPU) folds the same
     fronts and top-k lists as the scalar oracle, compiles once per entry
-    count, and leaves the conv programs' lowered text as it was.
+    count, copies as many arrays a chunk whatever the widths, and leaves
+    the conv programs' lowered text as it was.
 """
 import dataclasses
 import hashlib
@@ -21,10 +23,12 @@ jax = pytest.importorskip("jax")
 from repro.configs import get_config
 from repro.configs.shapes import SHAPES, ShapeSpec
 from repro.core import oracle
-from repro.core.dataflow import (AcceleratorConfig, GemmLayer, LayerStack,
-                                 layer_energy_pj, layer_energy_pj_batch,
-                                 layer_table, simulate_layer,
-                                 simulate_layer_batch)
+from repro.core.dataflow import (TABLE_FEATURES, AcceleratorConfig,
+                                 GemmLayer, LayerStack, layer_energy_pj,
+                                 layer_energy_pj_batch, layer_table,
+                                 simulate_layer, simulate_layer_batch,
+                                 simulate_network_batch,
+                                 simulate_network_table)
 from repro.core.pe import PAPER_PE_TYPES
 from repro.core.workloads import (expert_token_counts, from_config,
                                   get_network)
@@ -49,6 +53,9 @@ LAYERS = [
               count=27),
     GemmLayer("av", 16, 32768, 512, groups=128, operand="cache", count=3),
     GemmLayer("expert", 3, 2048, 1408, count=268),
+    # an 8-bit key cache: fits a quarter of 256 KB and up at 8 bits
+    GemmLayer("index_keys", 64, 128, 512, groups=8, operand="cache",
+              bits=8, count=61),
 ]
 
 DEEPSEEK = get_config("deepseek-v2-lite")
@@ -56,6 +63,11 @@ DECODE = SHAPES["decode_32k"]
 # 2 MoE layers of 8 experts, 8 sequences at 256 cached positions
 TINY = dataclasses.replace(DEEPSEEK, n_layers=3, n_experts=8)
 TINY_DECODE = ShapeSpec("tiny_decode", 256, 8, "decode")
+V32 = get_config("deepseek-v3.2")
+DECODE_128K = ShapeSpec("decode_128k", 131072, 32, "decode")
+# 1 dense and 2 MoE layers of 8 experts; the indexer picks 64 of 256
+TINY_V32 = dataclasses.replace(V32, n_layers=3, first_dense_layers=1,
+                               n_experts=8, index_topk=64)
 
 
 def configs(n=240, seed=0):
@@ -119,6 +131,50 @@ def test_cache_operand_moves_at_activation_bits():
   assert c.utilization == one.utilization
 
 
+def test_stored_width_moves_the_right_operand():
+  # INT16: 16-bit activations and weights
+  cfg = AcceleratorConfig(pe_type="INT16", gbuf_kb=64, pe_cols=16,
+                          bandwidth_gbps=6.4)
+  cache = GemmLayer("c", 2, 576, 4096, groups=4, operand="cache")
+  at = {bits: simulate_layer(cfg, dataclasses.replace(cache, bits=bits),
+                             1000.0) for bits in (None, 16, 8)}
+  assert at[16] == at[None]  # a stored width equal to act_bits changes nothing
+  assert at[8].dram_reads == at[None].dram_reads  # the same words
+  assert 0.0 < at[8].dram_stall_cycles < at[None].dram_stall_cycles
+  energy = {b: layer_energy_pj(cfg, dataclasses.replace(cache, bits=b),
+                               at[b], 1000.0, 1.0) for b in (None, 8)}
+  assert energy[8] < energy[None]
+  # a weight at a width of its own, too; widths are part of the shape
+  weight = dataclasses.replace(cache, operand="weight")
+  assert simulate_layer(cfg, dataclasses.replace(weight, bits=16), 1000.0) \
+      == simulate_layer(cfg, weight, 1000.0)
+  assert len({cache.shape, dataclasses.replace(cache, bits=16).shape}) == 2
+  for bits in (0, 1):  # the table's codes of the PE type's widths
+    with pytest.raises(ValueError, match="bits"):
+      dataclasses.replace(cache, bits=bits)
+
+
+def test_existing_entries_keep_every_number():
+  # sha256 of the table path's latency, energy and utilization on fixed
+  # design points, clocks and leakage, taken before entries could store
+  # a width: conv layers and V2-Lite's cache entries are unchanged
+  table = DesignSpace().sample_table(60, seed=21)
+  c = oracle.batch_inputs(table)
+  clock = 250.0 + np.arange(len(table)) * 0.5
+  leak = 1.0 + np.arange(len(table)) * 0.01
+  want = {
+      "resnet20": "fe0d5d8e0a97a06c7197fe3acfa474321fe233805efc43a00da301ee0a4bb147",
+      "v2lite": "75df7ed6a11fb395c6cd958913d1ef90489ecb4d713cbbed829359fb7519aa11"}
+  for name, layers in (("resnet20", get_network("resnet20")),
+                       ("v2lite", from_config(DEEPSEEK, DECODE))):
+    cols, counts = layer_table(layers)
+    for out in (simulate_network_table(c, cols, counts, clock, leak),
+                simulate_network_batch(c, layers, clock, leak)):
+      digest = hashlib.sha256(b"".join(
+          np.ascontiguousarray(o, np.float64).tobytes() for o in out))
+      assert digest.hexdigest() == want[name], name
+
+
 def test_counts_weight_the_network():
   cfg = AcceleratorConfig(pe_type="INT16")
   net = [LAYERS[0], LAYERS[2]]
@@ -172,6 +228,58 @@ def test_deepseek_decode_lowers_to_the_published_counts():
   assert sum(l.count for l in entries) == 5286
 
 
+def v32_weight_macs_per_token(c):
+  """Weight MACs of one DeepSeek-V3.2 token from the published widths
+  (arXiv:2405.04434 Sec. 2.1-2.2; the V3.2-Exp report's indexer): the
+  query latent and its up-projection, the indexer's queries, key and
+  head weights, and the rest as V2-Lite."""
+  d, q = c.d_model, c.q_lora_rank
+  hi, di = c.index_n_heads, c.index_head_dim
+  qk = c.head_dim + c.qk_rope_head_dim
+  compressed = d * q + q * c.n_heads * qk - d * c.n_heads * qk
+  indexer = q * hi * di + d * di + d * hi
+  return weight_macs_per_token(c) + c.n_layers * (compressed + indexer)
+
+
+@pytest.mark.parametrize("ctx", [131072, 1024], ids=["L>topk", "L<topk"])
+def test_deepseek_v3_2_decode_lowers_to_the_published_counts(ctx):
+  shape = ShapeSpec("decode", ctx, 32, "decode")
+  entries = from_config(V32, shape)
+  k = min(V32.index_topk, ctx)  # the positions the attention reads
+  weights = [l for l in entries if l.operand == "weight"]
+  assert sum(l.count * l.macs for l in weights) == \
+      32 * v32_weight_macs_per_token(V32)
+  # per sequence and layer: the indexer's scores against its keys and
+  # their weighted sum, then the attention over the k chosen positions
+  r, dr = V32.kv_lora_rank, V32.qk_rope_head_dim
+  per_seq = 64 * 128 * ctx + 64 * ctx + 128 * ((r + dr) + r) * k
+  caches = [l for l in entries if l.operand == "cache"]
+  assert sum(l.count * l.macs for l in caches) == 32 * 61 * per_seq
+  words = {b: sum(l.count * l.groups * l.K * l.N for l in caches
+                  if l.bits == b) for b in (None, 8)}
+  assert words == {8: 32 * 61 * 128 * ctx,
+                   None: 32 * 61 * (64 * ctx + (r + dr + r) * k)}
+  assert [l.name for l in entries[:13]] == [
+      "attn.q_a", "attn.q_b", "attn.q_absorb", "attn.kv_a_proj", "index.q",
+      "index.k", "index.w", "index.scores", "index.reduce", "attn.scores",
+      "attn.av", "attn.v_absorb", "attn.o_proj"]
+  assert [l.bits for l in entries].count(8) == 1
+  assert len({l.shape for l in entries}) == len(entries)
+  assert entries[-1].name == "lm_head" and entries[-1].N == 129280
+  # one SwiGLU MLP (gate, up, down) per routed expert with tokens
+  routed = sum(l.count for l in entries if l.name.startswith("moe.expert"))
+  assert routed == 3 * sum(int((t > 0).sum())
+                           for t in expert_token_counts(V32, 32))
+
+
+def test_without_an_indexer_the_attention_reads_every_position():
+  dense = from_config(dataclasses.replace(V32, index_topk=0), DECODE_128K)
+  assert not [l for l in dense if l.name.startswith("index.")]
+  assert [(l.name, l.N) for l in dense if l.name == "attn.scores"] == \
+      [("attn.scores", 131072)]
+  assert all(l.bits is None for l in dense)
+
+
 def test_cache_reads_in_the_dram_model():
   # with M = 16 heads on 16 columns or more, each sequence's cache is
   # streamed from DRAM once: the reads are the lowering's cache words
@@ -216,6 +324,8 @@ def test_lowering_raises_for_what_it_does_not_lower():
     from_config(DEEPSEEK, SHAPES["prefill_32k"])
   with pytest.raises(NotImplementedError, match="latent attention"):
     from_config(get_config("qwen2-moe-a2.7b"), DECODE)
+  with pytest.raises(NotImplementedError, match="indexer without"):
+    from_config(dataclasses.replace(V32, q_lora_rank=0), DECODE)
   with pytest.raises(ValueError, match="routing"):
     from_config(DEEPSEEK, DECODE, routing=[np.zeros(64)] * 3)
 
@@ -258,11 +368,12 @@ def answers(red):
 
 
 @pytest.mark.parametrize("which,n_per_type,chunk", [
-    ("tiny", 60, 64), ("full", 75, 128)])
+    ("tiny", 60, 64), ("full", 75, 128), ("v3.2", 60, 64)])
 def test_table_program_folds_the_scalar_oracles_fronts(which, n_per_type,
                                                        chunk):
-  layers = from_config(TINY, TINY_DECODE) if which == "tiny" \
-      else from_config(DEEPSEEK, DECODE)
+  layers = {"tiny": lambda: from_config(TINY, TINY_DECODE),
+            "full": lambda: from_config(DEEPSEEK, DECODE),
+            "v3.2": lambda: from_config(TINY_V32, TINY_DECODE)}[which]()
   want = answers(scalar_answers(layers, n_per_type, 9, reducers()))
   for jit in (False, True):
     red = reducers()
@@ -272,6 +383,9 @@ def test_table_program_folds_the_scalar_oracles_fronts(which, n_per_type,
         reducers=red, chunk_size=chunk)
     assert res.meta["n_layer_shapes"] == len(layers)
     assert res.meta["n_layer_slots"] == sum(l.count for l in layers)
+    assert res.meta["n_cache_shapes"] == \
+        {"tiny": 2, "full": 2, "v3.2": 4}[which]
+    assert res.meta["n_narrow_shapes"] == (which == "v3.2")
     got = answers(red)
     for name in want:
       np.testing.assert_array_equal(got[name][0], want[name][0])
@@ -315,6 +429,30 @@ def test_layer_table_copy_is_counted_in_place():
       device_variations=True).values())
   assert res.meta["n_chunks"] == 4  # one chunk of 32 points per PE type
   assert res.meta["bytes_to_device"] == 4 * (table_bytes + inputs)
+
+
+def test_stored_widths_add_no_array_to_place(monkeypatch):
+  # the width rides in the ``cache`` column: a chunk of the V3.2 step
+  # copies as many arrays as one of V2-Lite's, 12 of them the table's
+  from repro.explore.backend import VectorOracleBackend as Backend
+  place, leaves = Backend._place, []
+
+  def counted(inputs, dev, commit=False):
+    leaves.append(len(jax.tree_util.tree_leaves(inputs)))
+    return place(inputs, dev, commit)
+  monkeypatch.setattr(Backend, "_place", staticmethod(counted))
+  per_chunk = {}
+  for name, cfg in (("v2", TINY), ("v3.2", TINY_V32)):
+    layers = from_config(cfg, TINY_DECODE)
+    cols, counts = layer_table(layers)
+    assert tuple(cols) == TABLE_FEATURES and len(cols) + 1 == 12
+    del leaves[:]
+    ExplorationSession(Backend(chunk_size=64, jit=True),
+                       DesignSpace()).explore(
+        layers, "net", n_per_type=16, seed=5, stream=True,
+        reducers=reducers(), chunk_size=64)
+    per_chunk[name] = set(leaves)
+  assert per_chunk["v2"] == per_chunk["v3.2"] and len(per_chunk["v2"]) == 1
 
 
 def test_conv_networks_through_the_table_are_bit_identical():

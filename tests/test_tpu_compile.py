@@ -10,7 +10,10 @@ inputs, same flags as on the chip — at a reduced block:
     2,048 HW rows; the 10M-pair sweep's real block is 104 x 2,500, which
     takes minutes to compile;
   * the fused plain program (2-D Pareto + top-100) for all 21 resnet20
-    layers at 4,096 rows; the real chunk is 65,536 rows.
+    layers at 4,096 rows; the real chunk is 65,536 rows;
+  * the fused layer-table program (the same reducers) over DeepSeek-V3.2's
+    decode step at 128k, 45 entries with an 8-bit key cache among them,
+    at 4,096 rows.
 
 Both take the x64 bundle, whose variation columns the program derives
 from uint64 keys: the chip compiles the f64 -> u64 convert of the
@@ -138,6 +141,26 @@ def test_fused_plain_program_compiles_for_v5e(one_chip):
   compiled = _compile(_plain_program(), (
       {k: _on_chip(v, one_chip, PLAIN_ROWS)
        for k, v in _inputs_bundle().items()},))
+  _check(compiled)
+
+
+def test_fused_table_program_compiles_for_v5e(one_chip):
+  from repro.configs import get_config
+  from repro.configs.shapes import ShapeSpec
+  from repro.core.dataflow import layer_table
+  from repro.core.workloads import from_config
+  layers = from_config(get_config("deepseek-v3.2"),
+                       ShapeSpec("decode_128k", 131072, 32, "decode"))
+  cols, counts = layer_table(layers)
+  assert len(counts) == 45 and cols["cache"].max() == 8.0
+  plan = device_lib.build_plan(
+      {"pareto": ParetoAccumulator(),
+       "top": TopKAccumulator(100, by="energy_mj")}, joint=False)
+  compiled = _compile(device_lib.make_table_fn(plan), (
+      {k: _on_chip(v, one_chip, PLAIN_ROWS)
+       for k, v in _inputs_bundle().items()},
+      {k: _on_chip(v, one_chip) for k, v in cols.items()},
+      _on_chip(counts, one_chip)))
   _check(compiled)
 
 
